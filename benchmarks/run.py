@@ -139,9 +139,9 @@ def bench_greedytl_incremental(quick: bool):
     deep-accepting production-shape problem (cap=160 -> R=1120, D=23,
     M=16), per-refine jitted dispatch counts, and the ``loo_trials``
     autotuner table. Updates results/benchmarks/greedytl_incremental.json
-    and the repo-level BENCH_greedytl.json trajectory (quick runs refresh
-    the refine/dispatch numbers; the paper_tables cold/warm subprocess
-    timings only re-measure on a full run)."""
+    and the repo-level BENCH_greedytl.json trajectory (the refine/dispatch
+    numbers are refreshed; the recorded paper_tables cold/warm CPU wall
+    times are carried over, no longer measured)."""
     import jax
     import jax.numpy as jnp
     from benchmarks.paper_tables import RESULTS_DIR
@@ -195,43 +195,6 @@ def bench_greedytl_incremental(quick: bool):
                  f"{kernel_ops.autotune_key(cap * C, M + C, M)} -> "
                  f"{entry['impl']}"))
 
-    tables = None
-    if not quick:
-        import subprocess
-        import tempfile
-        code = ("import time; t0 = time.time(); "
-                "from benchmarks.paper_tables import run_all; "
-                "run_all(quick=True); print('WALL_S', time.time() - t0)")
-        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-        tables_json = os.path.join(RESULTS_DIR, "paper_tables.json")
-        keep = open(tables_json).read() if os.path.exists(tables_json) \
-            else None
-
-        def run_once(cache_dir):
-            env = dict(os.environ,
-                       PYTHONPATH="src" + os.pathsep
-                       + os.environ.get("PYTHONPATH", ""),
-                       JAX_COMPILATION_CACHE_DIR=cache_dir)
-            out = subprocess.run([sys.executable, "-c", code], cwd=root,
-                                 env=env, capture_output=True, text=True,
-                                 check=True)
-            return float(out.stdout.strip().split()[-1])
-
-        try:
-            with tempfile.TemporaryDirectory() as cd:
-                cold = run_once(cd)
-                warm = run_once(cd)
-        finally:
-            if keep is not None:        # quick subprocess must not clobber
-                with open(tables_json, "w") as f:
-                    f.write(keep)
-        tables = {"cold_s": round(cold, 1), "warm_jit_cache_s":
-                  round(warm, 1)}
-        rows.append(("paper_tables_quick_cold", cold * 1e6,
-                     "subprocess, fresh jit cache"))
-        rows.append(("paper_tables_quick_warm", warm * 1e6,
-                     "subprocess, persistent jit cache"))
-
     os.makedirs(RESULTS_DIR, exist_ok=True)
     path = os.path.join(RESULTS_DIR, "greedytl_incremental.json")
     payload = {}
@@ -251,8 +214,6 @@ def bench_greedytl_incremental(quick: bool):
                            "key": kernel_ops.autotune_key(cap * C, M + C,
                                                           M),
                            "entry": entry}
-    if tables is not None:
-        payload["paper_tables_quick_wall_s"] = tables
     with open(path, "w") as f:
         json.dump(payload, f, indent=1)
         f.write("\n")
@@ -274,15 +235,11 @@ def bench_greedytl_incremental(quick: bool):
                  "deep_refine_us": deep["incremental_us"],
                  "deep_refine_speedup_vs_refactor": deep["speedup"],
                  "deep_refine_depth": deep["depth"]}
-    if tables is not None:
-        entry_row["paper_tables_quick_cold_s"] = tables["cold_s"]
-        entry_row["paper_tables_quick_warm_s"] = tables["warm_jit_cache_s"]
-    else:
-        prev = {r["label"]: r for r in traj["trajectory"]}
-        old = prev.get("pr7_incremental_carry", {})
-        for k in ("paper_tables_quick_cold_s", "paper_tables_quick_warm_s"):
-            if k in old:
-                entry_row[k] = old[k]
+    prev = {r["label"]: r for r in traj["trajectory"]}
+    old = prev.get("pr7_incremental_carry", {})
+    for k in ("paper_tables_quick_cold_s", "paper_tables_quick_warm_s"):
+        if k in old:
+            entry_row[k] = old[k]
     traj["trajectory"] = [r for r in traj["trajectory"]
                           if r["label"] != entry_row["label"]]
     traj["trajectory"].append(entry_row)
@@ -978,6 +935,8 @@ def main():
                     help="scenario learning-round engine for the tables")
     args, _ = ap.parse_known_args()
 
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     sections = [bench_sweep_api, bench_parallel_sweep,
                 bench_hosts_launcher, bench_sweep_service, bench_greedytl,

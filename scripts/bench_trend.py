@@ -3,10 +3,9 @@
 regress past the committed trajectory.
 
 Re-measures the ``paper_tables --quick`` cold (fresh jit cache) and warm
-(persistent jit cache) subprocess wall times — the same measurement
-``benchmarks/run.py::bench_greedytl_incremental`` records into
-BENCH_greedytl.json on full runs — and fails when either exceeds the
-latest trajectory entry by more than ``--threshold`` (default 1.25x,
+(persistent jit cache) subprocess wall times — the CPU measurement behind
+the table timings in BENCH_greedytl.json — and fails when either exceeds
+the latest trajectory entry by more than ``--threshold`` (default 1.25x,
 i.e. a >25% regression). Writes the measurement next to the other bench
 artifacts as results/benchmarks/bench_trend.json so the nightly workflow
 uploads a comparable trend point per run.
@@ -22,12 +21,15 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import shutil
 import subprocess
 import sys
-import tempfile
 import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# fixed path (a cache directory that moves never hits); emptied before the
+# cold run, reused by the warm one
+CACHE_DIR = os.path.join(ROOT, ".jax_cache", "bench_trend")
 sys.path.insert(0, os.path.join(ROOT, "src"))
 sys.path.insert(0, ROOT)
 
@@ -80,9 +82,9 @@ def main() -> int:
     keep = open(tables_json).read() if os.path.exists(tables_json) \
         else None
     try:
-        with tempfile.TemporaryDirectory() as cd:
-            cold = run_tables_once(cd)
-            warm = run_tables_once(cd)
+        shutil.rmtree(CACHE_DIR, ignore_errors=True)
+        cold = run_tables_once(CACHE_DIR)
+        warm = run_tables_once(CACHE_DIR)
     finally:
         if keep is not None:
             with open(tables_json, "w") as f:

@@ -26,7 +26,7 @@ StarHTL fleet of ``fleet_size`` DCs, each drawing ``obs_per_dc``
 observations per window *on device* (per-DC ``fold_in`` PRNG keys, so the
 draw is shard-count invariant), sharded over the DC mesh axis
 (:func:`repro.sharding.partitioning.fleet_mesh`) with
-``jax.experimental.shard_map``. No per-DC Python objects exist; fleet
+``jax.shard_map``. No per-DC Python objects exist; fleet
 state stays device-resident across the whole scan; cross-shard reductions
 are exact (one-hot ``psum`` for the source pool and center dataset,
 lexicographic max for the entropy election), so shard counts 1..8 produce
@@ -55,7 +55,6 @@ from typing import List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import htl
@@ -471,9 +470,9 @@ def _city_program(W: int, L: int, K: int, shards: int, num_classes: int,
                                          jnp.arange(W, dtype=jnp.int32))
         return cms, centers
 
-    fn = shard_map(mapped, mesh=mesh,
-                   in_specs=(P(), P(), P(), P(), P(), P(), P(), P()),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(mapped, mesh=mesh,
+                       in_specs=(P(), P(), P(), P(), P(), P(), P(), P()),
+                       out_specs=(P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -550,13 +549,12 @@ def _city_death_schedule(cfg, L0: int, L: int) -> np.ndarray:
     return t_die
 
 
-def run_city(cfg, data: Dataset, *, max_shards: Optional[int] = None):
-    """The city scenario: ``cfg.fleet_size`` DCs, ``cfg.obs_per_dc``
-    observations each per window, StarHTL, one jitted dispatch for the
-    whole run. ``max_shards`` caps the DC-mesh width (default: every
-    visible device whose count divides the padded fleet)."""
-    from repro.core.scenario import ScenarioResult
-
+def city_outputs(cfg, data: Dataset, *, max_shards: Optional[int] = None
+                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """The city program's raw per-window outputs: confusion counts
+    ``(W, C, C)``, elected centre gids ``(W,)`` and the death schedule
+    ``t_die`` it ran with. ``max_shards`` caps the DC-mesh width (default:
+    every visible device whose count divides the padded fleet)."""
     L0, K, W = cfg.fleet_size, cfg.obs_per_dc, cfg.windows
     L = city_fleet_pad(L0)
     shards = dc_shards(L, max_shards)
@@ -568,7 +566,17 @@ def run_city(cfg, data: Dataset, *, max_shards: Optional[int] = None):
         program, xtr, ytr, x_test, y_oh,
         jnp.float32(cfg.global_update_rate), jnp.int32(L0),
         jax.random.PRNGKey(cfg.seed), jnp.asarray(t_die))
-    cms, centers = np.asarray(cms), np.asarray(centers)
+    return np.asarray(cms), np.asarray(centers), t_die
+
+
+def run_city(cfg, data: Dataset, *, max_shards: Optional[int] = None):
+    """The city scenario: ``cfg.fleet_size`` DCs, ``cfg.obs_per_dc``
+    observations each per window, StarHTL, one jitted dispatch for the
+    whole run (:func:`city_outputs`), energy charged analytically."""
+    from repro.core.scenario import ScenarioResult
+
+    L0, K, W = cfg.fleet_size, cfg.obs_per_dc, cfg.windows
+    cms, centers, t_die = city_outputs(cfg, data, max_shards=max_shards)
 
     ledger = Ledger()
     for t in range(W):

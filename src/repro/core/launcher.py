@@ -67,7 +67,8 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.parallel import (SweepExecutor, merge_shard_payloads,
-                                 partition_runs, run_shard_payload)
+                                 partition_runs, refuse_child_interpreters,
+                                 run_shard_payload)
 from repro.core.registry import format_spec, parse_spec, register_factory
 from repro.core.scenario import ScenarioConfig
 from repro.data.synthetic_covtype import Dataset
@@ -325,6 +326,7 @@ class LocalChannel(HostChannel):
         return [f"local/{i}" for i in range(self.n)]
 
     def run(self, slot, request, *, timeout=None, extra_env=None):
+        refuse_child_interpreters("hosts:channel=local")
         cmd = [sys.executable, "-m", "repro.core.launcher", "--worker"]
         return _communicate(cmd, request, timeout=timeout,
                             extra_env=extra_env, where=slot)
@@ -808,6 +810,8 @@ class HostsExecutor(SweepExecutor):
 def main(argv: Optional[Sequence[str]] = None) -> int:
     import argparse
 
+    from repro.core.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser(
         prog="repro.core.launcher",
         description="Shard worker for the multi-host sweep launcher "
@@ -819,6 +823,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                                     "from this JSON file")
     ap.add_argument("--output", help="file mode: write the response here")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.input or args.output:
         if not (args.input and args.output):

@@ -145,6 +145,32 @@ def assert_host_only(obj: Any, where: str = "payload") -> None:
 
 
 # ---------------------------------------------------------------------------
+# one process per chip
+# ---------------------------------------------------------------------------
+
+class ChipHeldError(RuntimeError):
+    """A backend that starts child interpreters was asked to run where this
+    process holds a TPU: the children would fail or hang on the chip."""
+
+
+def refuse_child_interpreters(backend: str) -> None:
+    """Raise :class:`ChipHeldError` when this process runs on a TPU.
+
+    A TPU belongs to one process at a time, and a process that has
+    touched JAX holds it; the spawned pool (``processes``) and the
+    ``hosts:channel=local`` workers would each need the chip again. On a
+    TPU host, run sweeps with ``none``, ``devices`` or
+    ``hosts:channel=inline``, which stay in this process."""
+    import jax
+
+    if jax.default_backend() == "tpu":
+        raise ChipHeldError(
+            f"{backend} starts child interpreters, but the TPU belongs to "
+            f"one process and this one holds it; use parallel='none', "
+            f"'devices:n=K' or 'hosts:channel=inline' on a TPU host")
+
+
+# ---------------------------------------------------------------------------
 # execution backends
 # ---------------------------------------------------------------------------
 
@@ -338,6 +364,7 @@ class _ProcessShardExecutor(SweepExecutor):
     def execute(self, labels, cfgs, data, *, stack):
         import multiprocessing as mp
 
+        refuse_child_interpreters(f"processes:n={self.n}")
         shards = [s for s in partition_runs(cfgs, self.n) if s]
         tasks = []
         for idxs in shards:

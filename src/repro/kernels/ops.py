@@ -150,6 +150,16 @@ def _persist_cache_locked() -> None:
         pass                      # read-only checkout: memory cache only
 
 
+def autotune_table(backend=None) -> dict:
+    """``{bucket key: entry}`` the autotuner holds for ``backend``
+    (default: the current one): the shapes measured in this process plus
+    any loaded from the persisted table."""
+    backend = backend or jax.default_backend()
+    with _tune_lock:
+        return {key: dict(e) for (b, key), e in sorted(_tune_mem.items())
+                if b == backend}
+
+
 def reset_autotune_cache() -> None:
     """Drop the in-memory cache and force a disk reload (test hook)."""
     global _tune_disk_loaded
@@ -217,21 +227,21 @@ def autotune_loo_trials(r: int, d: int, m: int, *, backend=None,
         np.abs(rng.standard_normal(m)).astype(f32),        # dinv
     ))
 
+    cands = (candidates if candidates is not None
+             else _default_candidates(backend))
+    if not cands:
+        raise ValueError("autotune_loo_trials needs at least one candidate")
     timings = {}
-    for impl, br in (candidates if candidates is not None
-                     else _default_candidates(backend)):
+    # a candidate that fails to compile raises: on the chip a kernel that
+    # Mosaic refuses must surface, not quietly become the jnp path
+    for impl, br in cands:
         if impl == "jnp":
             label, fn = "jnp", jax.jit(_loo.loo_trials_ref)
         else:
             label = f"pallas@{br}"
             fn = functools.partial(_loo.loo_trials, block_r=br,
                                    interpret=backend != "tpu")
-        try:
-            timings[label] = round(_time_call(fn, args, reps), 2)
-        except Exception:          # candidate fails to lower: skip it
-            continue
-    if not timings:
-        timings["jnp"] = 0.0       # degenerate candidate list: fall back
+        timings[label] = round(_time_call(fn, args, reps), 2)
     best = min(timings, key=timings.get)
     entry = {
         "impl": "jnp" if best == "jnp" else "pallas",

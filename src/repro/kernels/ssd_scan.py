@@ -29,24 +29,30 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
         h_scr[...] = jnp.zeros_like(h_scr)
 
     x = x_ref[0].astype(jnp.float32)          # (Q, P)
-    dt = dt_ref[0].astype(jnp.float32)        # (Q, 128) col 0 valid
-    dt = dt[:, :1]                            # (Q, 1)
-    A = a_ref[0, 0]                           # scalar for this head
+    dt_l = dt_ref[0].astype(jnp.float32)      # (Q, 128), dt on every lane
+    A = a_ref[0].astype(jnp.float32)          # (1, 128), A on every lane
     Bm = b_ref[0].astype(jnp.float32)         # (Q, N)
     Cm = c_ref[0].astype(jnp.float32)         # (Q, N)
 
-    da = dt * A                               # (Q,1)
-    cs = jnp.cumsum(da, axis=0)               # (Q,1)
+    # inclusive prefix sum of the log decays as a lower-triangular matmul
+    # (Mosaic has no cumsum); lanes stay replicated, so the row forms of
+    # dt and cs come from lane-aligned (Q, 128) transposes
+    iot_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    iot_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = iot_i >= iot_j
+    cs_l = jax.lax.dot(causal.astype(jnp.float32), dt_l * A,
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)   # (Q, 128)
+    dt, cs = dt_l[:, :1], cs_l[:, :1]          # (Q,1)
+    dt_row, cs_row = dt_l.T[:1, :], cs_l.T[:1, :]   # (1,Q)
     seg = cs[-1:, :]                          # (1,1) total chunk decay (log)
 
     # intra-chunk dual form
     scores = jax.lax.dot_general(Cm, Bm, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # (Q,Q)
-    decay = cs - cs.T                          # (Q,Q) log decay i<-j
-    iot_i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    iot_j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(iot_i >= iot_j, jnp.exp(decay), 0.0)
-    M = scores * L * dt.T                      # (Q,Q), dt_j on columns
+    decay = cs - cs_row                        # (Q,Q) log decay i<-j
+    L = jnp.where(causal, jnp.exp(decay), 0.0)
+    M = scores * L * dt_row                    # (Q,Q), dt_j on columns
     y_intra = jax.lax.dot(M, x, preferred_element_type=jnp.float32)
 
     # inter-chunk: contribution of the carried state
@@ -59,7 +65,11 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref,
 
     # state update: h = exp(seg) * h_prev + sum_j exp(seg - cs_j) dt_j B_j x_j
     w = jnp.exp(seg - cs) * dt                 # (Q,1)
-    new_state = jnp.exp(seg) * h_prev + jax.lax.dot_general(
+    # the chunk decay as an (1, N) row cut from the lane-replicated cs:
+    # Mosaic cannot broadcast a (1,1) value over sublanes and lanes at once
+    N = h_prev.shape[1]
+    seg_l = jnp.concatenate([cs_l[-1:, :]] * pl.cdiv(N, 128), axis=1)
+    new_state = jnp.exp(seg_l[:, :N]) * h_prev + jax.lax.dot_general(
         x * w, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)    # (P,N)
     h_scr[...] = new_state
@@ -87,7 +97,10 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = False):
     xf = x.transpose(0, 2, 1, 3).reshape(B * H, S, P)
     dtf = jnp.broadcast_to(dt.transpose(0, 2, 1).reshape(B * H, S)[..., None],
                            (B * H, S, 128))
-    af = jnp.tile(A, B).reshape(B * H, 1)
+    # per-head decay padded to a full lane row, like dt: a (1, 1) block
+    # over a (B*H, 1) array is not (8, 128)-tileable for Mosaic
+    af = jnp.broadcast_to(jnp.tile(A, B).reshape(B * H, 1, 1),
+                          (B * H, 1, 128))
 
     kernel = functools.partial(_ssd_kernel, chunk=Q, nheads=H)
     y, state = pl.pallas_call(
@@ -96,7 +109,7 @@ def ssd_scan(x, dt, A, Bm, Cm, *, chunk: int = 128, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((1, Q, P), lambda i, c: (i, c, 0)),
             pl.BlockSpec((1, Q, 128), lambda i, c: (i, c, 0)),
-            pl.BlockSpec((1, 1), lambda i, c: (i, 0)),
+            pl.BlockSpec((1, 1, 128), lambda i, c: (i, 0, 0)),
             pl.BlockSpec((1, Q, N), lambda i, c: (i // H, c, 0)),
             pl.BlockSpec((1, Q, N), lambda i, c: (i // H, c, 0)),
         ],

@@ -452,9 +452,8 @@ def moe_ffn_shard_map(p, x, cfg: ModelConfig):
         aux = m.router_aux_coef * E * jnp.sum(f * jnp.mean(probs, axis=0))
         return y, aux
 
-    from jax.experimental.shard_map import shard_map
-    fn = shard_map(
-        local, mesh=mesh, check_rep=False,
+    fn = jax.shard_map(
+        local, mesh=mesh, check_vma=False,
         in_specs=(P_("model"), P_("model"), P_("model"), P_(), P_()),
         out_specs=(P_(), P_()))
     y, aux = fn(p["wi"], p["wg"], p["wo"], p["router"],

@@ -621,7 +621,7 @@ def service_from_spec(spec: str) -> Tuple[ThreadingHTTPServer, SweepService]:
     """Build server+service from one nested-grammar spec string
     (DESIGN.md §12), e.g.::
 
-        serve:port=8080;backend=hosts:channel=local,n=4;cache_dir=results/sweep_cache
+        serve:port=8080;backend=hosts:channel=inline,n=4;cache_dir=results/sweep_cache
 
     ``";"``-separated parameters with list continuation, so the embedded
     executor/channel specs nest without escaping (the same grammar as
@@ -640,18 +640,22 @@ def service_from_spec(spec: str) -> Tuple[ThreadingHTTPServer, SweepService]:
 def main(argv=None) -> int:
     import argparse
 
+    from repro.core.compile_cache import use_compile_cache
+
     ap = argparse.ArgumentParser(
         prog="repro.service.server",
         description="Streaming sweep service (DESIGN.md §12)")
     ap.add_argument("--spec", default=None,
                     help="full service spec, e.g. "
-                         "'serve:port=8080;backend=hosts:channel=local,"
-                         "n=4'")
+                         "'serve:port=8080;backend=hosts:channel=inline,"
+                         "n=4' (on a TPU host only the inline channel "
+                         "runs: the chip belongs to one process)")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--backend", default=DEFAULT_BACKEND)
     ap.add_argument("--cache-dir", default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.spec:
         httpd, service = service_from_spec(args.spec)
