@@ -58,7 +58,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.core import htl
-from repro.core.dispatch import count_dispatch
+from repro.core.dispatch import count_dispatch, span
 from repro.core.energy import (INDEX_BYTES, Ledger, MODEL_BYTES, OBS_BYTES)
 from repro.core.fleet import fleet_cap
 from repro.core.greedytl import _greedytl
@@ -134,55 +134,63 @@ def _plan_scenario(cfg, data: Dataset) -> Tuple[List[_WindowPlan], Ledger]:
     for the scan program."""
     from repro.core.scenario import ChurnBook, build_stream, collect_window
 
-    rng = np.random.default_rng(cfg.seed)
-    ledger = Ledger()
-    # realism axis rides along for free: the (possibly drifted) stream
-    # comes from the shared build_stream, churn/byzantine faults happen
-    # inside the shared collect_window — a churned-away window becomes an
-    # empty plan, masked by the scan program's ``learn`` flag (alive-state
-    # masking: jitted shapes never change, dead fleets are zero rows)
-    sx, sy = build_stream(cfg, data, rng)
-    churn = None if cfg.battery_mj is None else ChurnBook(cfg.battery_mj)
+    with span("plan", windows=cfg.windows) as sp:
+        rng = np.random.default_rng(cfg.seed)
+        ledger = Ledger()
+        # realism axis rides along for free: the (possibly drifted) stream
+        # comes from the shared build_stream, churn/byzantine faults happen
+        # inside the shared collect_window — a churned-away window becomes
+        # an empty plan, masked by the scan program's ``learn`` flag
+        # (alive-state masking: jitted shapes never change, dead fleets are
+        # zero rows)
+        sx, sy = build_stream(cfg, data, rng)
+        churn = (None if cfg.battery_mj is None
+                 else ChurnBook(cfg.battery_mj))
 
-    plans: List[_WindowPlan] = []
-    prev_exists = False
-    for t in range(cfg.windows):
-        s = slice(t * cfg.obs_per_window, (t + 1) * cfg.obs_per_window)
-        dcs = collect_window(cfg, rng, sx[s], sy[s], ledger,
-                             window=t, churn=churn)
-        if cfg.aggregate:
-            dcs = apply_aggregation_heuristic(dcs, ledger, cfg.tech)
-        live = [d for d in dcs if d.n > 0]
-        if not live:
-            plans.append(_WindowPlan([], []))
-            continue
-        if len(live) == 1:
-            plans.append(_WindowPlan(live, [], single=True))
+        plans: List[_WindowPlan] = []
+        prev_exists = False
+        live_dcs = 0
+        for t in range(cfg.windows):
+            s = slice(t * cfg.obs_per_window, (t + 1) * cfg.obs_per_window)
+            dcs = collect_window(cfg, rng, sx[s], sy[s], ledger,
+                                 window=t, churn=churn)
+            if cfg.aggregate:
+                dcs = apply_aggregation_heuristic(dcs, ledger, cfg.tech)
+            live = [d for d in dcs if d.n > 0]
+            live_dcs += len(live)
+            if not live:
+                plans.append(_WindowPlan([], []))
+                continue
+            if len(live) == 1:
+                plans.append(_WindowPlan(live, [], single=True))
+                prev_exists = True
+                continue
+            ap = htl._ap_name(live)
+            topo = Topology(ledger, cfg.tech, fleet_nodes(live, ap))
+            if cfg.algo == "a2a":
+                topo.exchange_all(MODEL_BYTES, what="m0 exchange")
+                refine = [htl._subsample(d, cfg.n_subsample, NUM_CLASSES,
+                                         rng) for d in live]
+                center = next((d for d in live if d.name == ap), live[0])
+                topo.gather(topo.node(center.name), MODEL_BYTES,
+                            what="m1 gather")
+            else:
+                topo.exchange_all(INDEX_BYTES, what="entropy index")
+                c_idx = int(np.argmax([htl.label_entropy(d.y, NUM_CLASSES)
+                                       for d in live]))
+                center = live[c_idx]
+                topo.broadcast(topo.node(center.name), INDEX_BYTES,
+                               what="center id")
+                topo.gather(topo.node(center.name), MODEL_BYTES,
+                            what="m0 to center")
+                refine = [htl._subsample(center, cfg.n_subsample,
+                                         NUM_CLASSES, rng)]
+            n_pool = min(len(live), M_CAP)
+            prev_slot = (len(live) if (prev_exists and len(live) < M_CAP)
+                         else -1)
+            plans.append(_WindowPlan(live, refine, n_pool, prev_slot))
             prev_exists = True
-            continue
-        ap = htl._ap_name(live)
-        topo = Topology(ledger, cfg.tech, fleet_nodes(live, ap))
-        if cfg.algo == "a2a":
-            topo.exchange_all(MODEL_BYTES, what="m0 exchange")
-            refine = [htl._subsample(d, cfg.n_subsample, NUM_CLASSES, rng)
-                      for d in live]
-            center = next((d for d in live if d.name == ap), live[0])
-            topo.gather(topo.node(center.name), MODEL_BYTES, what="m1 gather")
-        else:
-            topo.exchange_all(INDEX_BYTES, what="entropy index")
-            c_idx = int(np.argmax([htl.label_entropy(d.y, NUM_CLASSES)
-                                   for d in live]))
-            center = live[c_idx]
-            topo.broadcast(topo.node(center.name), INDEX_BYTES,
-                           what="center id")
-            topo.gather(topo.node(center.name), MODEL_BYTES,
-                        what="m0 to center")
-            refine = [htl._subsample(center, cfg.n_subsample, NUM_CLASSES,
-                                     rng)]
-        n_pool = min(len(live), M_CAP)
-        prev_slot = len(live) if (prev_exists and len(live) < M_CAP) else -1
-        plans.append(_WindowPlan(live, refine, n_pool, prev_slot))
-        prev_exists = True
+        sp.set_metadata(dcs=live_dcs, events=len(ledger.events))
     return plans, ledger
 
 
@@ -191,56 +199,64 @@ def _pack_plan(cfg, plans: List[_WindowPlan]) -> dict:
     — DC axis at the bucketed fleet capacity, samples at the max bucketed
     sample capacity over all windows — so one scan program serves every
     Poisson draw of the scenario."""
-    W = cfg.windows
-    F = NUM_CLASSES  # placeholder; fixed below from data
-    max_live = max([len(p.live) for p in plans] + [1])
-    L = fleet_cap(max_live)
-    cap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.live]
-              + [sample_cap(1, cfg.cap)])
-    rcap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.refine]
-               + [sample_cap(1, cfg.cap)])
-    feats = [d.x.shape[1] for p in plans for d in p.live]
-    F = feats[0] if feats else 1
+    with span("pack") as sp:
+        W = cfg.windows
+        F = NUM_CLASSES  # placeholder; fixed below from data
+        max_live = max([len(p.live) for p in plans] + [1])
+        L = fleet_cap(max_live)
+        cap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.live]
+                  + [sample_cap(1, cfg.cap)])
+        rcap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.refine]
+                   + [sample_cap(1, cfg.cap)])
+        feats = [d.x.shape[1] for p in plans for d in p.live]
+        F = feats[0] if feats else 1
 
-    xb = np.zeros((W, L, cap, F), np.float32)
-    yb = np.zeros((W, L, cap), np.int32)
-    mb = np.zeros((W, L, cap), np.float32)
-    dcm = np.zeros((W, L), np.float32)
-    src_base = np.zeros((W, M_CAP), np.float32)
-    src_prev = np.zeros((W, M_CAP), np.float32)
-    n_live = np.zeros((W,), np.float32)
-    learn = np.zeros((W,), bool)
-    single = np.zeros((W,), bool)
-    if cfg.algo == "a2a":
-        xr = np.zeros((W, L, rcap, F), np.float32)
-        yr = np.zeros((W, L, rcap), np.int32)
-        mr = np.zeros((W, L, rcap), np.float32)
-    else:
-        xr = np.zeros((W, rcap, F), np.float32)
-        yr = np.zeros((W, rcap), np.int32)
-        mr = np.zeros((W, rcap), np.float32)
-
-    for t, p in enumerate(plans):
-        for i, d in enumerate(p.live):
-            xb[t, i], yb[t, i], mb[t, i] = pad_local(d.x, d.y, cap)
-            dcm[t, i] = 1.0
-        n_live[t] = len(p.live)
-        learn[t] = bool(p.live)
-        single[t] = p.single
-        if p.single or not p.live:
-            continue
-        src_base[t, :p.n_pool] = 1.0
-        if p.prev_slot >= 0:
-            src_prev[t, p.prev_slot] = 1.0
+        xb = np.zeros((W, L, cap, F), np.float32)
+        yb = np.zeros((W, L, cap), np.int32)
+        mb = np.zeros((W, L, cap), np.float32)
+        dcm = np.zeros((W, L), np.float32)
+        src_base = np.zeros((W, M_CAP), np.float32)
+        src_prev = np.zeros((W, M_CAP), np.float32)
+        n_live = np.zeros((W,), np.float32)
+        learn = np.zeros((W,), bool)
+        single = np.zeros((W,), bool)
         if cfg.algo == "a2a":
-            for i, d in enumerate(p.refine):
-                xr[t, i], yr[t, i], mr[t, i] = pad_local(d.x, d.y, rcap)
+            xr = np.zeros((W, L, rcap, F), np.float32)
+            yr = np.zeros((W, L, rcap), np.int32)
+            mr = np.zeros((W, L, rcap), np.float32)
         else:
-            xr[t], yr[t], mr[t] = pad_local(p.refine[0].x, p.refine[0].y,
-                                            rcap)
-    return {"xb": xb, "yb": yb, "mb": mb, "dcm": dcm, "xr": xr, "yr": yr,
-            "mr": mr, "src_base": src_base, "src_prev": src_prev,
-            "n_live": n_live, "learn": learn, "single": single}
+            xr = np.zeros((W, rcap, F), np.float32)
+            yr = np.zeros((W, rcap), np.int32)
+            mr = np.zeros((W, rcap), np.float32)
+
+        rows = 0                  # observations placed in the sample slots
+        for t, p in enumerate(plans):
+            for i, d in enumerate(p.live):
+                xb[t, i], yb[t, i], mb[t, i] = pad_local(d.x, d.y, cap)
+                dcm[t, i] = 1.0
+                rows += min(d.n, cap)
+            n_live[t] = len(p.live)
+            learn[t] = bool(p.live)
+            single[t] = p.single
+            if p.single or not p.live:
+                continue
+            src_base[t, :p.n_pool] = 1.0
+            if p.prev_slot >= 0:
+                src_prev[t, p.prev_slot] = 1.0
+            if cfg.algo == "a2a":
+                for i, d in enumerate(p.refine):
+                    xr[t, i], yr[t, i], mr[t, i] = pad_local(d.x, d.y, rcap)
+                    rows += min(d.n, rcap)
+            else:
+                xr[t], yr[t], mr[t] = pad_local(p.refine[0].x,
+                                                p.refine[0].y, rcap)
+                rows += min(p.refine[0].n, rcap)
+        out = {"xb": xb, "yb": yb, "mb": mb, "dcm": dcm, "xr": xr, "yr": yr,
+               "mr": mr, "src_base": src_base, "src_prev": src_prev,
+               "n_live": n_live, "learn": learn, "single": single}
+        sp.set_metadata(slots=mb.size + mr.size, rows=rows,
+                        bytes=sum(a.nbytes for a in out.values()))
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -259,11 +275,21 @@ def _scan_program(algo: str, num_classes: int, iters: int,
 
     def body(carry, inp, eta, x_test, y_oh):
         w, has_g = carry
-        base = jax.vmap(
-            lambda xi, yi, mi: _train_svm(xi, yi, mi,
-                                          num_classes=num_classes,
-                                          iters=iters)
-        )(inp["xb"], inp["yb"], inp["mb"])               # (L, F+1, C)
+        with jax.named_scope("htl.svm"):
+            base = jax.vmap(
+                lambda xi, yi, mi: _train_svm(xi, yi, mi,
+                                              num_classes=num_classes,
+                                              iters=iters)
+            )(inp["xb"], inp["yb"], inp["mb"])           # (L, F+1, C)
+        with jax.named_scope("htl.greedytl"):
+            w2, has2 = refine_and_combine(w, has_g, base, inp, eta)
+        with jax.named_scope("htl.eval"):
+            cm = _window_cm(w2, x_test, y_oh, num_classes)
+        return (w2, has2), cm
+
+    def refine_and_combine(w, has_g, base, inp, eta):
+        """GreedyTL over the source pool, the combine and the global
+        update: the window's new global model and its flag."""
         L = base.shape[0]
         basep = (base[:M_CAP] if L >= M_CAP else
                  jnp.concatenate([base, jnp.zeros((M_CAP - L,) +
@@ -303,9 +329,7 @@ def _scan_program(algo: str, num_classes: int, iters: int,
         new = jnp.where(inp["single"], single_new, multi_new)
         upd = jnp.where(has_g, (1.0 - eta) * w + eta * new, new)
         w2 = jnp.where(inp["learn"], upd, w)
-        has2 = has_g | inp["learn"]
-        cm = _window_cm(w2, x_test, y_oh, num_classes)
-        return (w2, has2), cm
+        return w2, has_g | inp["learn"]
 
     @jax.jit
     def program(inputs, eta, x_test, y_oh):
@@ -329,19 +353,23 @@ def run_scenario_scan(cfg, data: Dataset):
     """The whole scenario as ONE jitted dispatch (parity path of the scan
     engine — ledgers exactly equal to the fleet engine's, F1 through the
     streamed confusion counts)."""
-    from repro.core.scenario import ScenarioResult
+    from repro.core.scenario import ScenarioResult, resolve_robust
 
-    from repro.core.scenario import resolve_robust
-
-    plans, ledger = _plan_scenario(cfg, data)
-    inputs = jax.tree.map(jnp.asarray, _pack_plan(cfg, plans))
-    x_test, y_oh = _eval_arrays(data)
-    program = _scan_program(cfg.algo, NUM_CLASSES, cfg.train_iters,
-                            resolve_robust(cfg.robust_agg))
-    cms = np.asarray(_dispatch_scan(program, inputs,
-                                    jnp.float32(cfg.global_update_rate),
-                                    x_test, y_oh))
-    return ScenarioResult(_f1_curve(cms, cfg.eval_every), ledger, cfg)
+    with span("scenario", windows=cfg.windows):
+        plans, ledger = _plan_scenario(cfg, data)
+        packed = _pack_plan(cfg, plans)
+        with span("upload", bytes=sum(a.nbytes for a in packed.values())):
+            inputs = jax.tree.map(jnp.asarray, packed)
+            eta = jnp.float32(cfg.global_update_rate)
+            x_test, y_oh = _eval_arrays(data)
+        program = _scan_program(cfg.algo, NUM_CLASSES, cfg.train_iters,
+                                resolve_robust(cfg.robust_agg))
+        cms = _dispatch_scan(program, inputs, eta, x_test, y_oh)
+        with span("fetch"):
+            cms = np.asarray(cms)
+        with span("result"):
+            return ScenarioResult(_f1_curve(cms, cfg.eval_every), ledger,
+                                  cfg)
 
 
 # ---------------------------------------------------------------------------

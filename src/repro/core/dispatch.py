@@ -13,6 +13,14 @@ A "dispatch" here is one Python-level call into a jitted entry point — the
 unit of host-sync / executable-launch overhead the fleet engine exists to
 amortise. Counting wraps the function object itself, so the gate also
 catches loops hidden inside helper modules, not just the engine drivers.
+
+The same module holds the host spans (:func:`span`): named intervals
+``htl.<step>`` in the ``jax.profiler`` trace, on the clock of the device's
+own timeline, so a profile of any ``SweepSpec.run`` shows what the host
+does between device programs: planning, packing, upload, launch, fetch
+and the result (DESIGN.md §15 lists every span and its counts). A span
+adds no synchronisation and costs well under a microsecond with no
+profiler running, so a program does the same work traced or not.
 """
 from __future__ import annotations
 
@@ -22,21 +30,35 @@ from contextlib import contextmanager
 from functools import wraps
 from typing import Mapping
 
+from jax.profiler import TraceAnnotation
+
 _COUNTS: Counter = Counter()
 # The parallel sweep executor (repro.core.parallel) dispatches shards from
 # several threads (devices backend) and merges counts shipped back from
 # worker processes (processes backend), so all counter mutation is locked.
 _LOCK = threading.Lock()
 
+SPAN_PREFIX = "htl."
+
+
+def span(name: str, **counts) -> TraceAnnotation:
+    """Context manager: the host span ``htl.<name>`` in the profiler's
+    trace, carrying ``counts`` as metadata. Counts known only at the end
+    of the step are attached with ``.set_metadata(**counts)`` on the
+    object the ``with`` yields."""
+    return TraceAnnotation(SPAN_PREFIX + name, **counts)
+
 
 def count_dispatch(name: str):
-    """Decorator: count Python-level calls into a jitted entry point."""
+    """Decorator: count Python-level calls into a jitted entry point, each
+    inside an ``htl.dispatch`` span that names the entry point."""
     def deco(fn):
         @wraps(fn)
         def wrapper(*args, **kwargs):
             with _LOCK:
                 _COUNTS[name] += 1
-            return fn(*args, **kwargs)
+            with span("dispatch", entry=name):
+                return fn(*args, **kwargs)
         wrapper.__wrapped__ = fn
         return wrapper
     return deco

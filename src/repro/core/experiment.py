@@ -41,6 +41,7 @@ from typing import (Any, Callable, Dict, List, Mapping, Optional, Sequence,
 
 import numpy as np
 
+from repro.core.dispatch import span
 from repro.core.energy import Ledger
 from repro.core.scenario import (ScenarioConfig, ScenarioResult,
                                  validate_config)
@@ -267,18 +268,20 @@ class SweepSpec:
 
         if stack not in ("auto", "off"):
             raise ValueError(f"stack must be 'auto' or 'off', got {stack!r}")
-        executor = get_executor(parallel)
-        runs = self.configs()
-        for _, cfg in runs:
-            validate_config(cfg)
-        results, exec_meta = executor.execute_with_meta(
-            [lbl for lbl, _ in runs], [cfg for _, cfg in runs], data,
-            stack=(stack == "auto"))
-        records = records_from([lbl for lbl, _ in runs], results)
-        out = SweepResult(name=self.name, records=records)
-        if exec_meta:
-            out.meta.update(exec_meta)
-        return out
+        with span("sweep") as sp:
+            executor = get_executor(parallel)
+            runs = self.configs()
+            sp.set_metadata(rows=len(runs))
+            for _, cfg in runs:
+                validate_config(cfg)
+            results, exec_meta = executor.execute_with_meta(
+                [lbl for lbl, _ in runs], [cfg for _, cfg in runs], data,
+                stack=(stack == "auto"))
+            records = records_from([lbl for lbl, _ in runs], results)
+            out = SweepResult(name=self.name, records=records)
+            if exec_meta:
+                out.meta.update(exec_meta)
+            return out
 
 
 # ---------------------------------------------------------------------------
