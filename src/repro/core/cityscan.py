@@ -9,8 +9,9 @@ A host-side *planner* replays the scenario's host work exactly as the fleet
 engine would — same rng consumption order (collection, then GreedyTL
 subsampling), same per-pair ledger events in the same order, same AP/center
 election and single-DC early exits — but instead of dispatching per window
-it packs every window's padded fleet blocks into ``(W, ...)`` arrays. One
-jitted ``lax.scan`` over windows then fuses base training -> GreedyTL
+it packs every window's fleet into ``(W, ...)`` arrays, the observations
+as one compact row table that the program pads into blocks on the device.
+One jitted ``lax.scan`` over windows then fuses base training -> GreedyTL
 refine -> EMA into a single carried fleet state ``(w_global, has_global)``,
 and evaluation is *streamed*: each window emits an integer confusion matrix
 (exact in f32 — counts < 2^24), from which the host recovers the paper's
@@ -64,7 +65,7 @@ from repro.core.fleet import fleet_cap
 from repro.core.greedytl import _greedytl
 from repro.core.htl import DC, M_CAP, apply_aggregation_heuristic
 from repro.core.metrics import f_measure_from_confusion
-from repro.core.svm import _train_svm, pad_local, sample_cap
+from repro.core.svm import _train_svm, sample_cap
 from repro.core.topology import Node, Topology, fleet_nodes, get_transport
 from repro.data.synthetic_covtype import Dataset, NUM_CLASSES
 from repro.sharding.partitioning import FLEET_AXIS, dc_shards, fleet_mesh
@@ -194,69 +195,124 @@ def _plan_scenario(cfg, data: Dataset) -> Tuple[List[_WindowPlan], Ledger]:
     return plans, ledger
 
 
+def _row_bucket(n: int) -> int:
+    """Rows of the uploaded table for ``n`` placed observations: a power of
+    two from 1024, so a preset's scenarios share programs."""
+    return max(1024, 1 << max(n - 1, 0).bit_length())
+
+
+def _slot_counts(shape, per_window: np.ndarray, n: np.ndarray, cap: int
+                 ) -> np.ndarray:
+    """Rows placed in each slot of a ``(W, L)`` (or ``(W,)``) block:
+    window ``t``'s ``per_window[t]`` DCs, of ``n`` observations each in
+    table order, fill its first slots, ``min(n, cap)`` rows apiece."""
+    c = np.zeros(shape, np.int32)
+    win = np.repeat(np.arange(len(per_window)), per_window)
+    first = np.repeat(np.cumsum(per_window) - per_window, per_window)
+    c.reshape(len(per_window), -1)[win, np.arange(len(win)) - first] = \
+        np.minimum(n, cap)
+    return c
+
+
+def _placement(counts: np.ndarray, cap: int, first: int):
+    """Where a block's rows sit: the table row of every slot's first
+    observation (``first`` plus the running sum of the counts before it,
+    in slot order) and the flat index, in a ``(..., cap)`` block, of the
+    slot each of the block's table rows fills, in table order."""
+    c = counts.ravel()
+    ends = np.cumsum(c)
+    starts = ends - c
+    slot_of_row = (np.repeat(np.arange(c.size) * cap - starts, c)
+                   + np.arange(int(ends[-1])))
+    return (starts + first).reshape(counts.shape).astype(np.int32), \
+        slot_of_row
+
+
 def _pack_plan(cfg, plans: List[_WindowPlan]) -> dict:
-    """Second pass: pad every window onto one stable (W, ...) block layout
+    """Second pass: lay every window onto one stable (W, ...) block layout
     — DC axis at the bucketed fleet capacity, samples at the max bucketed
     sample capacity over all windows — so one scan program serves every
-    Poisson draw of the scenario."""
+    Poisson draw of the scenario.
+
+    The observations go up once, compact: ``x_rows`` holds every live DC's
+    first ``min(n, cap)`` rows and then the refine rows, in slot order,
+    zero rows up to :func:`_row_bucket`; ``xb_start``/``xb_count`` and
+    ``xr_start``/``xr_count`` place them, and the scan program builds the
+    zero-padded ``xb``/``xr`` blocks from them on the device
+    (:func:`_scan_inputs`). Labels and masks keep their padded blocks."""
     with span("pack") as sp:
         W = cfg.windows
-        F = NUM_CLASSES  # placeholder; fixed below from data
-        max_live = max([len(p.live) for p in plans] + [1])
-        L = fleet_cap(max_live)
-        cap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.live]
-                  + [sample_cap(1, cfg.cap)])
-        rcap = max([sample_cap(d.n, cfg.cap) for p in plans for d in p.refine]
-                   + [sample_cap(1, cfg.cap)])
-        feats = [d.x.shape[1] for p in plans for d in p.live]
-        F = feats[0] if feats else 1
+        multi = np.array([bool(p.live) and not p.single for p in plans])
+        lens = np.array([len(p.live) for p in plans])
+        rlens = np.array([len(p.refine) for p in plans]) * multi
+        base = [d for p in plans for d in p.live]          # table order
+        refine = [d for p, m in zip(plans, multi) if m for d in p.refine]
+        base_n = np.array([d.n for d in base], np.int64)
+        refine_n = np.array([d.n for d in refine], np.int64)
+        L = fleet_cap(max(int(lens.max()), 1))
+        # sample_cap is monotone in n: the largest DC sets the bucket
+        cap = sample_cap(int(base_n.max(initial=1)), cfg.cap)
+        rcap = sample_cap(int(refine_n.max(initial=1)), cfg.cap)
+        F = base[0].x.shape[1] if base else 1
 
-        xb = np.zeros((W, L, cap, F), np.float32)
+        rshape = (W, L) if cfg.algo == "a2a" else (W,)
+        cb = _slot_counts((W, L), lens, base_n, cap)
+        cr = _slot_counts(rshape, rlens, refine_n, rcap)
+        n_base, n_rows = int(cb.sum()), int(cb.sum() + cr.sum())
+        xb_start, b_slots = _placement(cb, cap, 0)
+        xr_start, r_slots = _placement(cr, rcap, n_base)
+        x_rows = np.zeros((_row_bucket(n_rows), F), np.float32)
+        if base:
+            np.concatenate([d.x[:cap] for d in base]
+                           + [d.x[:rcap] for d in refine],
+                           out=x_rows[:n_rows])
         yb = np.zeros((W, L, cap), np.int32)
         mb = np.zeros((W, L, cap), np.float32)
-        dcm = np.zeros((W, L), np.float32)
-        src_base = np.zeros((W, M_CAP), np.float32)
-        src_prev = np.zeros((W, M_CAP), np.float32)
-        n_live = np.zeros((W,), np.float32)
-        learn = np.zeros((W,), bool)
-        single = np.zeros((W,), bool)
-        if cfg.algo == "a2a":
-            xr = np.zeros((W, L, rcap, F), np.float32)
-            yr = np.zeros((W, L, rcap), np.int32)
-            mr = np.zeros((W, L, rcap), np.float32)
-        else:
-            xr = np.zeros((W, rcap, F), np.float32)
-            yr = np.zeros((W, rcap), np.int32)
-            mr = np.zeros((W, rcap), np.float32)
+        yr = np.zeros(rshape + (rcap,), np.int32)
+        mr = np.zeros(rshape + (rcap,), np.float32)
+        for y, m, slots, dcs, k in ((yb, mb, b_slots, base, cap),
+                                    (yr, mr, r_slots, refine, rcap)):
+            if dcs:
+                y.reshape(-1)[slots] = np.concatenate([d.y[:k] for d in dcs])
+            m.reshape(-1)[slots] = 1.0
 
-        rows = 0                  # observations placed in the sample slots
-        for t, p in enumerate(plans):
-            for i, d in enumerate(p.live):
-                xb[t, i], yb[t, i], mb[t, i] = pad_local(d.x, d.y, cap)
-                dcm[t, i] = 1.0
-                rows += min(d.n, cap)
-            n_live[t] = len(p.live)
-            learn[t] = bool(p.live)
-            single[t] = p.single
-            if p.single or not p.live:
-                continue
-            src_base[t, :p.n_pool] = 1.0
-            if p.prev_slot >= 0:
-                src_prev[t, p.prev_slot] = 1.0
-            if cfg.algo == "a2a":
-                for i, d in enumerate(p.refine):
-                    xr[t, i], yr[t, i], mr[t, i] = pad_local(d.x, d.y, rcap)
-                    rows += min(d.n, rcap)
-            else:
-                xr[t], yr[t], mr[t] = pad_local(p.refine[0].x,
-                                                p.refine[0].y, rcap)
-                rows += min(p.refine[0].n, rcap)
-        out = {"xb": xb, "yb": yb, "mb": mb, "dcm": dcm, "xr": xr, "yr": yr,
-               "mr": mr, "src_base": src_base, "src_prev": src_prev,
-               "n_live": n_live, "learn": learn, "single": single}
-        sp.set_metadata(slots=mb.size + mr.size, rows=rows,
+        slot = np.arange(max(L, M_CAP))
+        n_pool = np.array([p.n_pool for p in plans]) * multi
+        prev = np.where(multi, [p.prev_slot for p in plans], -1)
+        out = {"x_rows": x_rows, "xb_start": xb_start, "xb_count": cb,
+               "yb": yb, "mb": mb,
+               "dcm": (slot[:L] < lens[:, None]).astype(np.float32),
+               "xr_start": xr_start, "xr_count": cr, "yr": yr, "mr": mr,
+               "src_base": (slot[:M_CAP] < n_pool[:, None]
+                            ).astype(np.float32),
+               "src_prev": (slot[:M_CAP] == prev[:, None]
+                            ).astype(np.float32),
+               "n_live": lens.astype(np.float32), "learn": lens > 0,
+               "single": np.array([p.single for p in plans], bool)}
+        sp.set_metadata(slots=mb.size + mr.size, rows=n_rows,
                         bytes=sum(a.nbytes for a in out.values()))
     return out
+
+
+def _unpack_block(x_rows, start, count, cap: int):
+    """The zero-padded ``(..., cap, F)`` sample block whose slot ``j`` of
+    each DC holds table row ``start + j`` for ``j < count``: a gather and a
+    select, so every value is the table's own bits or +0.0."""
+    j = jnp.arange(cap, dtype=jnp.int32)
+    valid = j < count[..., None]
+    rows = x_rows[jnp.where(valid, start[..., None] + j, 0)]
+    return jnp.where(valid[..., None], rows, jnp.float32(0.0))
+
+
+def _scan_inputs(x_rows, inp):
+    """A window's scan inputs from its slice of the uploaded
+    :func:`_pack_plan` arrays (or every window's, from all of them):
+    ``xb``/``xr`` built from the row table, the rest as uploaded."""
+    xs = dict(inp)
+    for blk, lab in (("xb", "yb"), ("xr", "yr")):
+        xs[blk] = _unpack_block(x_rows, xs.pop(blk + "_start"),
+                                xs.pop(blk + "_count"), xs[lab].shape[-1])
+    return xs
 
 
 # ---------------------------------------------------------------------------
@@ -267,14 +323,17 @@ def _pack_plan(cfg, plans: List[_WindowPlan]) -> dict:
 def _scan_program(algo: str, num_classes: int, iters: int,
                   trim: float = 0.0):
     """One jitted lax.scan over windows; jit re-specializes per block shape
-    (W, L, cap, rcap), all of which are bucketed, so the executable cache
+    (W, L, cap, rcap, N), all of which are bucketed, so the executable cache
     stays small across a sweep. ``trim`` > 0 swaps the A2A combine for the
     coordinate-wise trimmed mean (robust_agg="trim:frac=..."); the trace
     branches at Python level, so ``trim == 0`` compiles the exact
     pre-robust combine graph."""
 
-    def body(carry, inp, eta, x_test, y_oh):
+    def body(carry, inp, eta, x_test, y_oh, x_rows):
         w, has_g = carry
+        # each window's blocks are gathered here, not before the scan: the
+        # table and one window's blocks stay in the chip's fast memory
+        inp = _scan_inputs(x_rows, inp)
         with jax.named_scope("htl.svm"):
             base = jax.vmap(
                 lambda xi, yi, mi: _train_svm(xi, yi, mi,
@@ -333,11 +392,12 @@ def _scan_program(algo: str, num_classes: int, iters: int,
 
     @jax.jit
     def program(inputs, eta, x_test, y_oh):
-        F = inputs["xb"].shape[-1]
-        w0 = jnp.zeros((F + 1, num_classes), jnp.float32)
+        inputs = dict(inputs)
+        x_rows = inputs.pop("x_rows")
+        w0 = jnp.zeros((x_rows.shape[-1] + 1, num_classes), jnp.float32)
         carry0 = (w0, jnp.asarray(False))
         _, cms = jax.lax.scan(
-            partial(body, eta=eta, x_test=x_test, y_oh=y_oh),
+            partial(body, eta=eta, x_test=x_test, y_oh=y_oh, x_rows=x_rows),
             carry0, inputs)
         return cms
 
