@@ -28,6 +28,7 @@ class Driver:
     def __init__(self, run):
         self.run = run
         self.items = items(run.cell.config)
+        self.comparison = run.cell.comparison()
         self.done = []            # (scenario, t0, t1)
         self.first = {}           # scenario key -> (scenario, result)
 
@@ -68,25 +69,21 @@ class Driver:
 
     def outputs(self) -> list:
         """A sample drawn from the seed of the distinct scenarios that
-        finished in the window, each with what ``SweepSpec.run`` gave."""
+        finished in the window, each with what the comparison reads of
+        the result ``SweepSpec.run`` gave."""
         keys = sorted(self.first)
         k = min(int(self.run.params.get("compare", 3)), len(keys))
         rng = np.random.default_rng([self.run.seed, 0xC0])
         chosen = [keys[i] for i in sorted(rng.choice(len(keys), k,
                                                      replace=False))]
-        return [(self.first[key][0], answer_of(self.first[key][1]))
+        return [(self.first[key][0],
+                 self.comparison.answer(self.first[key][1].records[0]))
                 for key in chosen]
+
+    def rerun(self, scenario) -> dict:
+        """One scenario through the timed path again, outside the window,
+        as the comparison reads it (the fault readings)."""
+        return self.comparison.answer(self._one(scenario).records[0])
 
     def close(self) -> None:
         self.first.clear()
-
-
-def answer_of(result) -> dict:
-    """What a user reads of a one-row ``SweepResult``: the F1 curve and
-    the energy ledger's totals by purpose."""
-    rec = result.records[0]
-    return {"f1_curve": [float(v) for v in rec.f1_curve],
-            "collection_mj": sum(e["mj"] for e in rec.events
-                                 if e["purpose"] == "collection"),
-            "learning_mj": sum(e["mj"] for e in rec.events
-                               if e["purpose"] == "learning")}

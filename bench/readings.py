@@ -11,9 +11,10 @@ readings). For the ``--control`` seeds it also puts the reference
 computed one precision below the configuration's in the program's place
 on the same scenarios: the control's numbers (the upper readings). For
 the ``--faults`` seeds it runs the same scenarios again through the
-program with each fault of ``bench/faults.py`` planted. One JSON line per
-scenario and variant, with every per-point gap. The benchmark's own runs
-never do this.
+program with each fault of the configuration's ``bench/faults/<name>.py``
+planted. One JSON line per scenario and variant, with every number the
+configuration's comparison holds to a limit or prints, and under
+``gaps`` what they were reduced from. The benchmark's own runs never do this.
 """
 from __future__ import annotations
 
@@ -29,9 +30,7 @@ for p in (os.path.join(ROOT, "src"), ROOT):
     if p not in sys.path:
         sys.path.insert(0, p)
 
-from bench import check, faults  # noqa: E402
 from bench.data import dataset  # noqa: E402
-from bench.drivers.closed import answer_of  # noqa: E402
 from bench.run import Run, require_chips  # noqa: E402
 from bench.spec import load_cell  # noqa: E402
 
@@ -40,16 +39,19 @@ def readings(cell, seeds, control, fault_seeds, seconds, chip=True,
              out=None):
     import jax
     from repro.core.compile_cache import use_compile_cache
-    from repro.core.experiment import SweepSpec
 
     if chip:
         require_chips(cell.chips)
     use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    ref = cell.reference()
+    ref, comparison, faults = cell.reference(), cell.comparison(), \
+        cell.faults()
     rows = []
 
-    def emit(row):
+    def emit(base, variant, answer, want):
+        gaps = comparison.gaps(answer, want)
+        row = dict(base, variant=variant, **comparison.numbers([gaps]),
+                   gaps=gaps)
         rows.append(row)
         line = json.dumps(row)
         print(line, flush=True)
@@ -64,23 +66,20 @@ def readings(cell, seeds, control, fault_seeds, seconds, chip=True,
         driver.setup()
         driver.window(seconds)
         pairs = driver.outputs()
-        driver.close()
         for scenario, answer in pairs:
             want = ref.answer(scenario.plain(), run.data,
                               cell.config["reference_precision"])
             base = {"seed": seed, "scenario": scenario.key}
-            emit(dict(base, variant="program", **check.gaps(answer, want)))
+            emit(base, "program", answer, want)
             if seed in control:
-                low = ref.answer(scenario.plain(), run.data, "control")
-                emit(dict(base, variant="control", **check.gaps(low, want)))
+                emit(base, "control",
+                     ref.answer(scenario.plain(), run.data, "control"), want)
             if seed in fault_seeds:
                 for name in faults.FAULTS:
                     with faults.plant(name):
-                        got = answer_of(SweepSpec(
-                            cell.name, base=scenario.cfg,
-                            label=scenario.label).run(run.data))
-                    emit(dict(base, variant=name,
-                              **check.gaps(got, want)))
+                        got = driver.rerun(scenario)
+                    emit(base, name, got, want)
+        driver.close()
     return rows
 
 

@@ -164,14 +164,15 @@ def run_cell(cell, seed: int, seconds: float, traced: bool, *,
                 metrics[m["name"]] = {"value": values[m["name"]],
                                       "unit": m["unit"]}
 
-    reference = cell.reference()
+    reference, comparison = cell.reference(), cell.comparison()
     t_check = time.perf_counter()
     values = check.compare(pairs, reference, run.data,
-                           cell.config["reference_precision"])
-    checks = check.judge(values, cell.limits)
+                           cell.config["reference_precision"], comparison)
+    checks = check.judge(values, cell.limits, comparison.COMPARED)
+    printed = "".join(f"; {k} (held to no limit): {values[k]!r}"
+                      for k in comparison.PRINTED)
     print(f"bench: {len(pairs)} scenarios compared with the reference in "
-          f"{time.perf_counter() - t_check!r} s; f1_gap (held to no "
-          f"limit): {values['f1_gap']!r}", file=sys.stderr)
+          f"{time.perf_counter() - t_check!r} s{printed}", file=sys.stderr)
     correct = (bool(pairs) and window["failed"] == 0
                and all(c["value"] <= c["limit"] for c in checks.values()))
     out = {"correct": correct, "attempted": window["attempted"],

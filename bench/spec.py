@@ -4,12 +4,21 @@
 belongs to one configuration, one traffic mix, one cell or one per-layer
 metric sits in a file of its own under ``bench/``:
 
-* ``bench/configs/<config>.json``   the deployment's sizes and its reference;
+* ``bench/configs/<config>.json``   the deployment's sizes, its reference,
+                                    its comparison, its faults and the
+                                    size the harness's CPU tests run it at
+                                    (``cpu``);
 * ``bench/traffic/<traffic>.json``  the mix's parameters and its driver;
 * ``bench/cells/<cell>.json``       the cell's correctness limits;
 * ``bench/metrics/<metric>.py``     one per-layer metric's reader;
 * ``bench/drivers/<driver>.py``     a general generator, named by a mix;
-* ``bench/references/<ref>.py``     a plain reference, named by a config.
+* ``bench/references/<ref>.py``     a plain reference, named by a config;
+* ``bench/comparisons/<name>.py``   what ``correct`` compares (the numbers
+                                    held to limits and printed, and what a
+                                    user reads of a run), named by a config;
+* ``bench/faults/<name>.py``        the faults planted in the timed path
+                                    that must fail ``correct``, named by a
+                                    config.
 
 Adding a cell, a mix, a configuration or a metric adds files and entries;
 no existing file changes.
@@ -68,6 +77,16 @@ class Cell:
         return load_module(self.path("references", f"{name}.py"),
                            f"bench_reference_{name}")
 
+    def comparison(self):
+        name = self.config["comparison"]
+        return load_module(self.path("comparisons", f"{name}.py"),
+                           f"bench_comparison_{name}")
+
+    def faults(self):
+        name = self.config["faults"]
+        return load_module(self.path("faults", f"{name}.py"),
+                           f"bench_faults_{name}")
+
     def metric_reader(self, name: str):
         return load_module(self.path("metrics", f"{name}.py"),
                            f"bench_metric_{name}")
@@ -93,7 +112,13 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
                        f"{sorted(cells)}")
     w = cells[name]
     configs = {c["name"]: c for c in bench["configs"]}
-    config = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    config_file = configs[w["config"]]["file"]
+    config = load_json(os.path.join(root, config_file))
+    unnamed = [k for k in ("comparison", "faults") if k not in config]
+    if unnamed:
+        raise KeyError(f"{config_file} names no {' and no '.join(unnamed)}:"
+                       f" every configuration names what its correct "
+                       f"compares and the faults that must fail it")
     traffic = load_json(os.path.join(root, "bench", "traffic",
                                      f"{w['traffic']}.json"))
     cell_file = load_json(os.path.join(root, "bench", "cells",

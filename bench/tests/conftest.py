@@ -22,10 +22,13 @@ def root():
 
 
 def _shrink(cell):
-    """The cell at a size the CPU holds: 4-window preset rows, compared
-    with the reference in float32, which is what the program computes on a
-    CPU (its products are float32 there, not bfloat16 passes)."""
-    cell.config["preset_args"]["windows"] = 4
+    """The cell at a size the CPU holds: the configuration's ``cpu`` block
+    (each of its groups laid over the group of that name), compared with
+    the reference in float32, which is what the program computes on a CPU
+    (its products are float32 there, not bfloat16 passes)."""
+    for key, value in cell.config["cpu"].items():
+        cell.config[key] = (dict(cell.config[key], **value)
+                            if isinstance(value, dict) else value)
     cell.config["reference_precision"] = "float32"
     cell.params["compare"] = 2
     return cell
