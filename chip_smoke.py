@@ -18,7 +18,8 @@ One chip runs three phases through the entry points a user calls:
 
 ``--chips 4`` runs only the city engine sharded over ``fleet_mesh(4)``:
 10^6 DCs over four shards (peak memory per device), then 250,000 DCs on
-four shards against one, which must agree bitwise (DESIGN.md §10).
+four shards against one, which must agree bitwise: confusion counts,
+centres, global models and source pools (DESIGN.md §10).
 
 Cold (with compile) and warm wall times are smoke timings, not benchmark
 numbers. Any failed check raises and exits non-zero. Where JAX finds no
@@ -244,7 +245,8 @@ def phase_service(spec, data, expected_json: str) -> dict:
 def phase_shard_invariance(data, *, fleet_size: int, windows: int,
                            shards: int) -> dict:
     """``shards`` shards against one on the same host: confusion counts,
-    elected centres and F1 curves must be identical."""
+    elected centres, global models, source pools and F1 curves must be
+    identical."""
     from repro.sharding.partitioning import dc_shards
 
     cfg = get_preset("city", fleet_size=fleet_size,
@@ -252,14 +254,20 @@ def phase_shard_invariance(data, *, fleet_size: int, windows: int,
     require(dc_shards(city_fleet_pad(fleet_size), shards) == shards,
             f"{shards} shards do not divide the padded fleet or exceed "
             f"the devices")
-    (cm1, c1, _), t1 = timed(city_outputs, cfg, data, max_shards=1)
-    (cmn, cn, _), tn = timed(city_outputs, cfg, data, max_shards=shards)
-    f1 = [f_measure_from_confusion(c.astype(np.int64)) for c in cm1]
-    fn = [f_measure_from_confusion(c.astype(np.int64)) for c in cmn]
-    require(np.array_equal(cm1, cmn), "confusion counts differ by shards")
-    require(np.array_equal(c1, cn), "elected centres differ by shards")
+    one, t1 = timed(city_outputs, cfg, data, max_shards=1)
+    many, tn = timed(city_outputs, cfg, data, max_shards=shards)
+    f1 = [f_measure_from_confusion(c.astype(np.int64)) for c in one.cms]
+    fn = [f_measure_from_confusion(c.astype(np.int64)) for c in many.cms]
+    require(np.array_equal(one.cms, many.cms),
+            "confusion counts differ by shards")
+    require(np.array_equal(one.centres, many.centres),
+            "elected centres differ by shards")
+    require(np.array_equal(one.models, many.models),
+            "global models differ by shards")
+    require(np.array_equal(one.pools, many.pools),
+            "source pools differ by shards")
     require(f1 == fn, "F1 curves differ by shards")
-    return {"f1_curve": f1, "centres": [int(c) for c in c1],
+    return {"f1_curve": f1, "centres": [int(c) for c in one.centres],
             "one_shard_s": t1, f"{shards}_shards_s": tn}
 
 

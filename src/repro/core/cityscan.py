@@ -29,8 +29,9 @@ draw is shard-count invariant), sharded over the DC mesh axis
 (:func:`repro.sharding.partitioning.fleet_mesh`) with
 ``jax.shard_map``. No per-DC Python objects exist; fleet
 state stays device-resident across the whole scan; cross-shard reductions
-are exact (one-hot ``psum`` for the source pool and center dataset,
-lexicographic max for the entropy election), so shard counts 1..8 produce
+are exact (one-hot ``psum`` for the center dataset, lexicographic max for
+the entropy election, a lexicographic merge of every shard's best sources
+for the fleet-wide source screen), so shard counts 1..8 produce
 bitwise-identical results (tests/test_cityscan.py). Energy is charged
 analytically: per-role-pair transfer counts from the transport layer times
 combinatorial multiplicities — O(1) ledger events per window instead of
@@ -51,7 +52,7 @@ from __future__ import annotations
 
 import dataclasses
 from functools import lru_cache, partial
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -442,26 +443,94 @@ def city_fleet_pad(fleet_size: int) -> int:
     return fleet_cap(fleet_size)
 
 
-def _city_round(w, has_g, x, y, m, alive, gid, l0, eta, x_test, y_oh, *,
-                num_classes: int, iters: int, shards: int):
-    """One city StarHTL round; identical math sharded or not. ``x``/``y``/
-    ``m`` are this window's per-DC datasets (local shard rows), ``gid`` the
-    global DC ids, ``alive`` the churn-aware membership mask (valid AND
-    battery not yet depleted — without churn it equals the plain validity
-    mask and every value below is bitwise what it was pre-churn). All
-    cross-DC combination is either an exact one-hot psum (source pool,
-    center dataset) or a lexicographic max (entropy election), so the
-    round is bitwise shard-count invariant. Returns ``(w2, cm, cg, do)``
-    where ``do`` flags whether a learning round ran (>= 2 DCs alive; a
-    churned-to-nothing fleet keeps ``w`` untouched)."""
-    K = x.shape[1]
-    base = jax.vmap(
-        lambda xi, yi, mi: _train_svm(xi, yi, mi, num_classes=num_classes,
-                                      iters=iters))(x, y, m)
+def _screen_scores(base, cx, cy, *, num_classes: int,
+                   lam_src: float = 0.1, lam_bias: float = 2.0):
+    """GreedyTL's first-step objective for every DC's base model as a
+    source (``_greedy_select_incremental``'s trial score of candidate j at
+    the bias-only active set), on the centre's ``K`` observations ``cx``,
+    ``cy``. The bias-only factor is ``sqrt(K + lam_bias + 1e-4) I``, so
+    the bordering identity reduces to per-DC sums over the ``R = K*C``
+    stacked rows. Laid out ``(K, C, L)``, DCs on the minor axis, so no
+    per-DC ``(K, C)`` tile is padded. Returns ``(L,)``."""
+    K, F = cx.shape
+    C = num_classes
+    bt = jnp.transpose(base, (2, 1, 0))                   # (C, F+1, L)
+    H = jnp.einsum("kf,cfl->kcl", cx, bt[:, :F]) + bt[:, F][None]
+    s = jnp.sqrt(jnp.sum(H * H, axis=(0, 1)) / (K * C)) + 1e-6
+    Hn = H / s                                            # unit RMS
+    Y = (2.0 * jax.nn.one_hot(cy, C) - 1.0)[:, :, None]   # (K, C, 1)
+    B = K + lam_bias + 1e-4                               # bias Gram diag
+    g = jnp.sum(Hn, axis=0)                               # (C, L)
+    yb = jnp.sum(Y, axis=0)                               # (C, 1)
+    dsq = (jnp.sum(Hn * Hn, axis=(0, 1)) + (lam_src + 1e-4)
+           - jnp.sum(g * g, axis=0) / B)
+    dinv = jax.lax.rsqrt(jnp.maximum(dsq, 1e-8))
+    zj = (jnp.sum(Hn * Y, axis=(0, 1)) - jnp.sum(g * yb, axis=0) / B) * dinv
+    t = (Hn - g[None] / B) * dinv                         # bordering column
+    fitted = yb[None] / B + t * zj
+    h = 1.0 / B + t * t
+    loo = (fitted - Y) / jnp.maximum(1.0 - h, 0.1)
+    return jnp.sum(loo * loo, axis=(0, 1))
 
-    # entropy-based center election (paper Sec. 4), lexicographic tie-break
-    # on the global DC id so every shard layout elects the same center
-    cnt = jnp.sum(jax.nn.one_hot(y, num_classes) * m[:, :, None], axis=1)
+
+def _smallest(score, k: int):
+    """The ``k`` smallest scores in order and their indices, the lower
+    index first on a tie: ``k`` passes of ``argmin``, each taking its
+    entry out (+inf; once only +inf is left, the value read is +inf).
+    ``lax.top_k`` over 2.5e5 scores takes a v5e's compiler about a minute;
+    this, a second."""
+    def step(i, carry):
+        sc, val, idx = carry
+        j = jnp.argmin(sc).astype(jnp.int32)
+        return sc.at[j].set(jnp.inf), val.at[i].set(sc[j]), idx.at[i].set(j)
+
+    _, val, idx = jax.lax.fori_loop(
+        0, k, step, (score, jnp.zeros((k,), score.dtype),
+                     jnp.zeros((k,), jnp.int32)))
+    return val, idx
+
+
+def _screen(base, cx, cy, alive, gid, cg, *, num_classes: int, shards: int):
+    """The fleet-wide source screen (DESIGN.md §10): every alive DC but
+    the centre ``cg`` is scored by :func:`_screen_scores`, and the pool is
+    the first ``M_CAP`` by (score ascending, gid ascending). Each shard
+    keeps its own best ``M_CAP`` (:func:`_smallest`, the lower gid first
+    on a tie); the shards' (score, gid, model) triples are
+    all-gathered and merged by one two-key sort, so the pool is the same
+    bits at any shard count. Returns ``(src (M_CAP, F+1, C), src_mask
+    (M_CAP,), pool (M_CAP,) int32 gids, -1 where a slot is empty)``."""
+    score = jnp.where(alive & (gid != cg),
+                      _screen_scores(base, cx, cy, num_classes=num_classes),
+                      jnp.inf)
+    score, idx = _smallest(score, min(M_CAP, score.shape[0]))
+    pool, src = gid[idx], base[idx]
+    if shards > 1:
+        score, pool, src = (jax.lax.all_gather(a, FLEET_AXIS, tiled=True)
+                            for a in (score, pool, src))
+        order = jax.lax.sort(
+            (score, pool, jnp.arange(score.shape[0], dtype=jnp.int32)),
+            num_keys=2)[2]
+        score, pool, src = score[order], pool[order], src[order]
+    n = score.shape[0]
+    if n < M_CAP:
+        pad = M_CAP - n
+        score = jnp.concatenate([score, jnp.full((pad,), jnp.inf)])
+        pool = jnp.concatenate([pool, jnp.full((pad,), -1, pool.dtype)])
+        src = jnp.concatenate([src, jnp.zeros((pad,) + src.shape[1:])])
+    score, pool, src = score[:M_CAP], pool[:M_CAP], src[:M_CAP]
+    filled = jnp.isfinite(score)
+    return (jnp.where(filled[:, None, None], src, 0.0),
+            filled.astype(jnp.float32), jnp.where(filled, pool, -1))
+
+
+def _elect(y, m, alive, gid, *, num_classes: int, shards: int):
+    """Entropy-based centre election (paper Sec. 4): the highest label
+    entropy, the lowest global DC id on a tie, by a lexicographic max over
+    the shards. Entropies are taken over each DC's class counts sorted, so
+    DCs whose counts are a permutation of each other tie exactly on every
+    backend. Returns ``(centre gid, number of alive DCs)``."""
+    cnt = jnp.sort(jnp.sum(jax.nn.one_hot(y, num_classes) * m[:, :, None],
+                           axis=1), axis=1)
     tot = jnp.maximum(jnp.sum(cnt, axis=1), 1.0)
     p = cnt / tot[:, None]
     ent = -jnp.sum(jnp.where(p > 0, p * jnp.log(p), 0.0), axis=1) \
@@ -479,34 +548,50 @@ def _city_round(w, has_g, x, y, m, alive, gid, l0, eta, x_test, y_oh, *,
             ce = jnp.where(better, es[i], ce)
             cg = jnp.where(better, gs[i], cg)
         n_alive = jax.lax.psum(n_alive, FLEET_AXIS)
+    return cg, n_alive
 
-    # source pool: base models of the first min(L0, M_CAP) *alive* DCs'
-    # slots, gathered by exact one-hot psum (x + 0 == x bitwise); the mask
-    # is the same one-hot reduced, so dead DCs' slots leave the pool (with
-    # nobody dead it reduces to exactly the old ``slot < min(l0, M_CAP)``)
-    slot = jnp.arange(M_CAP, dtype=gid.dtype)
-    oh = ((gid[:, None] == slot[None, :]) & (slot[None, :] < l0)
-          & alive[:, None]).astype(jnp.float32)
-    src = jnp.einsum("lm,lfc->mfc", oh, base)
-    src_mask = jnp.sum(oh, axis=0)
 
-    # center's local dataset, same exact one-hot reduction
-    coh = (gid == cg).astype(jnp.float32)
-    cx = jnp.einsum("l,lkf->kf", coh, x)
-    cy = jnp.einsum("l,lk->k", coh, y.astype(jnp.float32))
-    if shards > 1:
-        src = jax.lax.psum(src, FLEET_AXIS)
-        src_mask = jax.lax.psum(src_mask, FLEET_AXIS)
-        cx = jax.lax.psum(cx, FLEET_AXIS)
-        cy = jax.lax.psum(cy, FLEET_AXIS)
-
-    refined, _ = _greedytl(cx, cy.astype(jnp.int32), jnp.ones((K,)),
-                           src, src_mask, num_classes=num_classes)
-    do = n_alive >= 2.0
-    upd = jnp.where(has_g, (1.0 - eta) * w + eta * refined, refined)
-    w2 = jnp.where(do, upd, w)
-    cm = _window_cm(w2, x_test, y_oh, num_classes)
-    return w2, cm, cg, do
+def _city_round(w, has_g, x, y, m, alive, gid, eta, x_test, y_oh, *,
+                num_classes: int, iters: int, shards: int):
+    """One city StarHTL round; identical math sharded or not. ``x``/``y``/
+    ``m`` are this window's per-DC datasets (local shard rows), ``gid`` the
+    global DC ids, ``alive`` the churn-aware membership mask (valid AND
+    battery not yet depleted). Cross-DC combination is a lexicographic
+    max (election), an exact one-hot psum (centre dataset) or a
+    lexicographic merge of gathered values (source screen), so the round
+    is bitwise shard-count invariant. Returns ``(w2, cm, cg, do, pool,
+    sel)``: ``do`` flags whether a learning round ran (>= 2 DCs alive; a
+    churned-to-nothing fleet keeps ``w`` untouched), ``pool`` the source
+    gids and ``sel`` GreedyTL's 0/1 choice among them."""
+    K = x.shape[1]
+    with jax.named_scope("htl.svm"):
+        base = jax.vmap(
+            lambda xi, yi, mi: _train_svm(xi, yi, mi,
+                                          num_classes=num_classes,
+                                          iters=iters))(x, y, m)
+    with jax.named_scope("htl.elect"):
+        cg, n_alive = _elect(y, m, alive, gid, num_classes=num_classes,
+                             shards=shards)
+        # centre's local dataset by exact one-hot reduction (x + 0 == x)
+        coh = (gid == cg).astype(jnp.float32)
+        cx = jnp.einsum("l,lkf->kf", coh, x)
+        cy = jnp.einsum("l,lk->k", coh, y.astype(jnp.float32))
+        if shards > 1:
+            cx = jax.lax.psum(cx, FLEET_AXIS)
+            cy = jax.lax.psum(cy, FLEET_AXIS)
+        cy = cy.astype(jnp.int32)
+    with jax.named_scope("htl.screen"):
+        src, src_mask, pool = _screen(base, cx, cy, alive, gid, cg,
+                                      num_classes=num_classes, shards=shards)
+    with jax.named_scope("htl.greedytl"):
+        refined, sel = _greedytl(cx, cy, jnp.ones((K,)), src, src_mask,
+                                 num_classes=num_classes)
+        do = n_alive >= 2.0
+        upd = jnp.where(has_g, (1.0 - eta) * w + eta * refined, refined)
+        w2 = jnp.where(do, upd, w)
+    with jax.named_scope("htl.eval"):
+        cm = _window_cm(w2, x_test, y_oh, num_classes)
+    return w2, cm, cg, do, pool, (sel * src_mask).astype(jnp.int32)
 
 
 def _draw_window(xtr, ytr, key, t, gid, validf, obs_per_dc: int):
@@ -545,22 +630,22 @@ def _city_program(W: int, L: int, K: int, shards: int, num_classes: int,
             w, has_g = carry
             alive = valid & (t < t_die_loc)
             alivef = alive.astype(jnp.float32)
-            x, y, m = _draw_window(xtr, ytr, key, t, gid, alivef, K)
-            w2, cm, cg, do = _city_round(
-                w, has_g, x, y, m, alive, gid, l0, eta, x_test, y_oh,
+            with jax.named_scope("htl.draw"):
+                x, y, m = _draw_window(xtr, ytr, key, t, gid, alivef, K)
+            w2, cm, cg, do, pool, sel = _city_round(
+                w, has_g, x, y, m, alive, gid, eta, x_test, y_oh,
                 num_classes=num_classes, iters=iters, shards=shards)
-            return (w2, has_g | do), (cm, cg)
+            return (w2, has_g | do), (cm, cg, w2, pool, sel)
 
         F = xtr.shape[1]
         carry0 = (jnp.zeros((F + 1, num_classes), jnp.float32),
                   jnp.asarray(False))
-        _, (cms, centers) = jax.lax.scan(body, carry0,
-                                         jnp.arange(W, dtype=jnp.int32))
-        return cms, centers
+        _, outs = jax.lax.scan(body, carry0, jnp.arange(W, dtype=jnp.int32))
+        return outs
 
     fn = jax.shard_map(mapped, mesh=mesh,
                        in_specs=(P(), P(), P(), P(), P(), P(), P(), P()),
-                       out_specs=(P(), P()), check_vma=False)
+                       out_specs=(P(),) * 5, check_vma=False)
     return jax.jit(fn)
 
 
@@ -637,12 +722,22 @@ def _city_death_schedule(cfg, L0: int, L: int) -> np.ndarray:
     return t_die
 
 
+class CityOutputs(NamedTuple):
+    """The city program's per-window outputs, and the death schedule it
+    ran with (DC ``i`` alive for windows ``t < t_die[i]``)."""
+    cms: np.ndarray          # (W, C, C) confusion counts
+    centres: np.ndarray      # (W,) elected centre gids
+    models: np.ndarray       # (W, F+1, C) global model after the window
+    pools: np.ndarray        # (W, M_CAP) source gids, -1 = empty slot
+    selected: np.ndarray     # (W, M_CAP) GreedyTL's 0/1 choice of them
+    t_die: np.ndarray        # (L,)
+
+
 def city_outputs(cfg, data: Dataset, *, max_shards: Optional[int] = None
-                 ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """The city program's raw per-window outputs: confusion counts
-    ``(W, C, C)``, elected centre gids ``(W,)`` and the death schedule
-    ``t_die`` it ran with. ``max_shards`` caps the DC-mesh width (default:
-    every visible device whose count divides the padded fleet)."""
+                 ) -> CityOutputs:
+    """The city program's raw per-window outputs (:class:`CityOutputs`).
+    ``max_shards`` caps the DC-mesh width (default: every visible device
+    whose count divides the padded fleet)."""
     L0, K, W = cfg.fleet_size, cfg.obs_per_dc, cfg.windows
     L = city_fleet_pad(L0)
     shards = dc_shards(L, max_shards)
@@ -650,34 +745,45 @@ def city_outputs(cfg, data: Dataset, *, max_shards: Optional[int] = None
     x_test, y_oh = _eval_arrays(data)
     t_die = _city_death_schedule(cfg, L0, L)
     program = _city_program(W, L, K, shards, NUM_CLASSES, cfg.train_iters)
-    cms, centers = _dispatch_city(
+    outs = _dispatch_city(
         program, xtr, ytr, x_test, y_oh,
         jnp.float32(cfg.global_update_rate), jnp.int32(L0),
         jax.random.PRNGKey(cfg.seed), jnp.asarray(t_die))
-    return np.asarray(cms), np.asarray(centers), t_die
+    with span("fetch"):
+        return CityOutputs(*(np.asarray(a) for a in outs), t_die)
 
 
 def run_city(cfg, data: Dataset, *, max_shards: Optional[int] = None):
     """The city scenario: ``cfg.fleet_size`` DCs, ``cfg.obs_per_dc``
     observations each per window, StarHTL, one jitted dispatch for the
-    whole run (:func:`city_outputs`), energy charged analytically."""
+    whole run (:func:`city_outputs`), energy charged analytically. The
+    result carries the global model after every window (``models``) and
+    each window's ``sources``: the elected centre, the source pool's gids
+    and GreedyTL's choice among them."""
     from repro.core.scenario import ScenarioResult
 
     L0, K, W = cfg.fleet_size, cfg.obs_per_dc, cfg.windows
-    cms, centers, t_die = city_outputs(cfg, data, max_shards=max_shards)
-
-    ledger = Ledger()
-    for t in range(W):
-        alive = t < t_die[:L0]
-        n_alive = int(alive.sum())
-        if n_alive > 0:
-            _charge_city_collection(ledger, n_alive, K)
-        if n_alive >= 2:
-            # the analytic AP role falls to the lowest-gid alive DC
-            ap_gid = int(np.argmax(alive))
-            _charge_city_learning(ledger, cfg.tech, n_alive,
-                                  center_is_ap=(int(centers[t]) == ap_gid))
-    return ScenarioResult(_f1_curve(cms, cfg.eval_every), ledger, cfg)
+    with span("city", dcs=L0, windows=W) as sp:
+        out = city_outputs(cfg, data, max_shards=max_shards)
+        ledger = Ledger()
+        for t in range(W):
+            alive = t < out.t_die[:L0]
+            n_alive = int(alive.sum())
+            if n_alive > 0:
+                _charge_city_collection(ledger, n_alive, K)
+            if n_alive >= 2:
+                # the analytic AP role falls to the lowest-gid alive DC
+                ap_gid = int(np.argmax(alive))
+                _charge_city_learning(
+                    ledger, cfg.tech, n_alive,
+                    center_is_ap=(int(out.centres[t]) == ap_gid))
+        # pool slots the first-M_CAP-ids rule could never have filled
+        sp.set_metadata(pool_beyond_ids=int(np.sum(out.pools >= M_CAP)))
+        return ScenarioResult(
+            _f1_curve(out.cms, cfg.eval_every), ledger, cfg,
+            models=out.models,
+            sources={"centre": out.centres, "pool": out.pools,
+                     "selected": out.selected})
 
 
 # ---------------------------------------------------------------------------
@@ -689,10 +795,10 @@ def run_city(cfg, data: Dataset, *, max_shards: Optional[int] = None):
 @lru_cache(maxsize=None)
 def _city_round_program(num_classes: int, iters: int):
     @jax.jit
-    def fn(w, has_g, x, y, m, alive, gid, l0, eta, x_test, y_oh):
-        return _city_round(w, has_g, x, y, m, alive, gid, l0, eta,
-                           x_test, y_oh, num_classes=num_classes,
-                           iters=iters, shards=1)
+    def fn(w, has_g, x, y, m, alive, gid, eta, x_test, y_oh):
+        return _city_round(w, has_g, x, y, m, alive, gid, eta, x_test,
+                           y_oh, num_classes=num_classes, iters=iters,
+                           shards=1)
     return fn
 
 
@@ -727,13 +833,10 @@ def run_city_perwindow(cfg, data: Dataset):
         idx = rng.integers(0, len(ytr_host), size=(L, K))
         xw = xtr_host[idx]                     # host gather, uploaded fresh
         yw = ytr_host[idx]
-        w_dev, cm, cg, do = program(jnp.asarray(w), jnp.asarray(has_g),
-                                    jnp.asarray(xw), jnp.asarray(yw),
-                                    jnp.asarray(m_host),
-                                    jnp.asarray(alive_host),
-                                    gid, jnp.int32(L0),
-                                    jnp.float32(cfg.global_update_rate),
-                                    x_test, y_oh)
+        w_dev, cm, cg, do, _, _ = program(
+            jnp.asarray(w), jnp.asarray(has_g), jnp.asarray(xw),
+            jnp.asarray(yw), jnp.asarray(m_host), jnp.asarray(alive_host),
+            gid, jnp.float32(cfg.global_update_rate), x_test, y_oh)
         w = np.asarray(w_dev)                  # per-window host sync
         has_g = bool(has_g or bool(do))
         cms[t] = np.asarray(cm)

@@ -290,15 +290,25 @@ class SweepSpec:
 
 @dataclass
 class RunRecord:
-    """One (label, seed) run: config, F1 curve, full energy-event ledger."""
+    """One (label, seed) run: config, F1 curve, full energy-event ledger;
+    for the city engine also the global model after every window and each
+    window's sources (:class:`ScenarioResult`), as nested lists (None on
+    the other engines)."""
     label: str
     cfg: ScenarioConfig
     f1_curve: List[float]
     events: List[dict]
+    models: Optional[list] = None
+    sources: Optional[Dict[str, list]] = None
 
     def to_scenario_result(self) -> ScenarioResult:
+        models = (None if self.models is None
+                  else np.asarray(self.models, np.float32))
+        sources = (None if self.sources is None else
+                   {k: np.asarray(v, np.int32)
+                    for k, v in self.sources.items()})
         return ScenarioResult(list(self.f1_curve), Ledger(list(self.events)),
-                              self.cfg)
+                              self.cfg, models=models, sources=sources)
 
 
 def records_from(labels: Sequence[str], results: Sequence[ScenarioResult]
@@ -308,7 +318,12 @@ def records_from(labels: Sequence[str], results: Sequence[ScenarioResult]
     (:mod:`repro.core.parallel`), so the record schema cannot drift
     between backends."""
     return [RunRecord(label=lbl, cfg=r.cfg, f1_curve=list(r.f1_curve),
-                      events=list(r.ledger.events))
+                      events=list(r.ledger.events),
+                      models=(None if r.models is None
+                              else np.asarray(r.models).tolist()),
+                      sources=(None if r.sources is None else
+                               {k: np.asarray(v).tolist()
+                                for k, v in r.sources.items()}))
             for lbl, r in zip(labels, results)]
 
 
@@ -405,12 +420,7 @@ class SweepResult:
         payload = {
             "schema": self.SCHEMA,
             "name": self.name,
-            "records": [{
-                "label": r.label,
-                "cfg": dataclasses.asdict(r.cfg),
-                "f1_curve": [float(v) for v in r.f1_curve],
-                "events": r.events,
-            } for r in self.records],
+            "records": [self._record_json(r) for r in self.records],
         }
         if include_meta and self.meta:
             payload["meta"] = self.meta
@@ -419,6 +429,18 @@ class SweepResult:
             with open(path, "w") as f:
                 f.write(text)
         return text
+
+    @staticmethod
+    def _record_json(r: RunRecord) -> Dict[str, Any]:
+        """A record's JSON form; ``models`` and ``sources`` appear only
+        where the engine gave them, so other records read as before."""
+        out = {"label": r.label, "cfg": dataclasses.asdict(r.cfg),
+               "f1_curve": [float(v) for v in r.f1_curve],
+               "events": r.events}
+        for key in ("models", "sources"):
+            if getattr(r, key) is not None:
+                out[key] = getattr(r, key)
+        return out
 
     @classmethod
     def from_json(cls, text: str) -> "SweepResult":
@@ -430,7 +452,9 @@ class SweepResult:
         records = [RunRecord(label=r["label"],
                              cfg=ScenarioConfig(**r["cfg"]),
                              f1_curve=list(r["f1_curve"]),
-                             events=list(r["events"]))
+                             events=list(r["events"]),
+                             models=r.get("models"),
+                             sources=r.get("sources"))
                    for r in payload["records"]]
         return cls(name=payload["name"], records=records,
                    meta=dict(payload.get("meta") or {}))
@@ -550,7 +574,7 @@ def _transport_grid(windows: int = 30, n_seeds: int = 1,
 
 
 @register_preset("city")
-def _city(fleet_size: int = 100_000, windows: int = 3, obs_per_dc: int = 4,
+def _city(fleet_size: Any = 100_000, windows: int = 3, obs_per_dc: int = 4,
           train_iters: int = 6, n_seeds: int = 1,
           tech: str = "wifi") -> SweepSpec:
     """The million-DC scaling scenario (ROADMAP north-star): a smart-city
@@ -558,12 +582,16 @@ def _city(fleet_size: int = 100_000, windows: int = 3, obs_per_dc: int = 4,
     device-resident fleet state, shard_map'd DC axis, one jitted dispatch
     for the whole run (repro.core.cityscan.run_city). Defaults are sized
     for the CI ``city-smoke`` gate: 10^5 DCs, 3 windows, trimmed base-SVM
-    iterations."""
+    iterations. A sequence of fleet sizes gives one row each."""
     base = ScenarioConfig(windows=windows, eval_every=1, algo="star",
-                          engine="scan", tech=tech, fleet_size=fleet_size,
-                          obs_per_dc=obs_per_dc, train_iters=train_iters)
+                          engine="scan", tech=tech, obs_per_dc=obs_per_dc,
+                          train_iters=train_iters)
+    if isinstance(fleet_size, (list, tuple)):
+        return SweepSpec(
+            "city", base=base, axes={"fleet_size": tuple(fleet_size)},
+            label="city_{fleet_size}dc_" + tech).with_seeds(n_seeds)
     return SweepSpec(
-        "city", base=base,
+        "city", base=dataclasses.replace(base, fleet_size=fleet_size),
         label=f"city_{fleet_size}dc_{tech}").with_seeds(n_seeds)
 
 

@@ -149,6 +149,12 @@ class ScenarioResult:
     f1_curve: List[float]
     ledger: Ledger
     cfg: ScenarioConfig
+    # city engine only (None elsewhere): the global model after every
+    # window, (W, F+1, C) float32, and each window's sources as int32
+    # arrays: ``centre`` (W,), the source ``pool`` gids (W, M_CAP; -1 =
+    # empty slot) and GreedyTL's 0/1 choice among them, ``selected``
+    models: Optional[np.ndarray] = None
+    sources: Optional[Dict[str, np.ndarray]] = None
 
     @property
     def final_f1(self) -> float:

@@ -111,3 +111,30 @@ def test_trace_changes_no_result_and_no_dispatch_count(traced):
     assert result.to_json() == untraced.to_json()
     assert delta == {"scan_windows": len(result.records)}
     assert np.isfinite([v for r in result.records for v in r.f1_curve]).all()
+
+
+def test_city_span_counts_the_fleet_and_the_screen(tmp_path):
+    """A city run writes one ``htl.city`` span around its dispatch, with
+    the fleet size, the window count and the pool slots over the run that
+    hold a DC the first-M_CAP-ids rule could not have chosen."""
+    from repro.core.cityscan import M_CAP
+
+    spec = get_preset("city", fleet_size=64, windows=2, obs_per_dc=14)
+    spec.run(DATA)                       # compiles outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        result = spec.run(DATA)
+    finally:
+        jax.profiler.stop_trace()
+    xplane = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                       recursive=True)
+    spans = _host_spans(xplane[0])
+    city = [s for s in spans if s[0] == "htl.city"]
+    assert len(city) == 1
+    pools = np.asarray(result.records[0].sources["pool"])
+    assert city[0][4] == {"dcs": 64, "windows": 2,
+                          "pool_beyond_ids": int((pools >= M_CAP).sum())}
+    assert city[0][4]["pool_beyond_ids"] > 0
+    dispatch = [s for s in spans if s[0] == "htl.dispatch"
+                and _inside(s, city[0])]
+    assert [s[4]["entry"] for s in dispatch] == ["city_scan"]

@@ -79,18 +79,29 @@ def test_loo_trials_compiles_for_v5e(one_chip, key, block_r):
 def test_city_round_fits_one_v5e(one_chip):
     """One city round at 250,000 DCs (K=4, train_iters=6, the city preset's
     widths) fits one chip's memory."""
+    _city_round_fits(one_chip, K=4, iters=6)
+
+
+def test_city_cell_round_fits_one_v5e(one_chip):
+    """The ``city-250k`` configuration's round (250,000 DCs, 14
+    observations each, 200 SVM iterations) fits one chip's memory."""
+    _city_round_fits(one_chip, K=14, iters=200)
+
+
+def _city_round_fits(one_chip, K: int, iters: int):
     from repro.core.cityscan import _city_round_program, city_fleet_pad
     from repro.data.synthetic_covtype import NUM_CLASSES, make_covtype_like
 
     data = make_covtype_like(seed=0)
-    L, K, F = city_fleet_pad(250_000), 4, data.x_train.shape[1]
+    L, F = city_fleet_pad(250_000), data.x_train.shape[1]
     n_test = data.x_test.shape[0]
     s = functools.partial(_shape, one_chip)
     args = (s((F + 1, NUM_CLASSES)), s((), jnp.bool_), s((L, K, F)),
             s((L, K), jnp.int32), s((L, K)), s((L,), jnp.bool_),
-            s((L,), jnp.int32), s((), jnp.int32), s(()), s((n_test, F)),
+            s((L,), jnp.int32), s(()), s((n_test, F)),
             s((n_test, NUM_CLASSES)))
-    compiled = _city_round_program(NUM_CLASSES, 6).lower(*args).compile()
+    compiled = _city_round_program(NUM_CLASSES, iters).lower(
+        *args).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes
