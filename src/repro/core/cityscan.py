@@ -66,7 +66,7 @@ from repro.core.fleet import fleet_cap
 from repro.core.greedytl import _greedytl
 from repro.core.htl import DC, M_CAP, apply_aggregation_heuristic
 from repro.core.metrics import f_measure_from_confusion
-from repro.core.svm import _train_svm, sample_cap
+from repro.core.svm import _train_svm, _train_svm_lanes, sample_cap
 from repro.core.topology import Node, Topology, fleet_nodes, get_transport
 from repro.data.synthetic_covtype import Dataset, NUM_CLASSES
 from repro.sharding.partitioning import FLEET_AXIS, dc_shards, fleet_mesh
@@ -565,10 +565,12 @@ def _city_round(w, has_g, x, y, m, alive, gid, eta, x_test, y_oh, *,
     gids and ``sel`` GreedyTL's 0/1 choice among them."""
     K = x.shape[1]
     with jax.named_scope("htl.svm"):
-        base = jax.vmap(
-            lambda xi, yi, mi: _train_svm(xi, yi, mi,
-                                          num_classes=num_classes,
-                                          iters=iters))(x, y, m)
+        # DCs on the lane axis (DESIGN.md §10); ``base`` is the (L, F+1,
+        # C) view of the trained (C, F+1, L), which the screen reads back
+        base = jnp.transpose(
+            _train_svm_lanes(jnp.transpose(x, (1, 2, 0)), y.T, m.T,
+                             num_classes=num_classes, iters=iters),
+            (2, 1, 0))
     with jax.named_scope("htl.elect"):
         cg, n_alive = _elect(y, m, alive, gid, num_classes=num_classes,
                              shards=shards)

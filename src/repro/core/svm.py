@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.dispatch import count_dispatch
+from repro.kernels.svm_lanes import svm_lanes
 
 
 SAMPLE_BUCKETS = (16, 64)   # bucketed per-DC sample capacities (< config cap)
@@ -55,6 +56,11 @@ def _hinge_loss(w, x, y_onehot_pm, mask, lam):
     return jnp.sum(per_sample) / denom + lam * jnp.sum(w[:-1] ** 2)
 
 
+def _rate(i, lr: float, iters: int):
+    """The cosine-decayed learning rate of step ``i``."""
+    return lr * 0.5 * (1 + jnp.cos(jnp.pi * i / iters))
+
+
 def _train_svm(x: jax.Array, y: jax.Array, mask: jax.Array, *,
                num_classes: int, lam: float = 1e-3, lr: float = 0.5,
                iters: int = 200, w0: jax.Array = None) -> jax.Array:
@@ -67,12 +73,47 @@ def _train_svm(x: jax.Array, y: jax.Array, mask: jax.Array, *,
     def body(i, carry):
         w, v = carry
         g = grad_fn(w, x, y_pm, mask, lam)
-        lr_i = lr * 0.5 * (1 + jnp.cos(jnp.pi * i / iters))
-        v = 0.9 * v - lr_i * g
+        v = 0.9 * v - _rate(i, lr, iters) * g
         return w + v, v
 
     w, _ = jax.lax.fori_loop(0, iters, body, (w_init, jnp.zeros_like(w_init)))
     return w
+
+
+def _train_svm_lanes(x: jax.Array, y: jax.Array, mask: jax.Array, *,
+                     num_classes: int, lam: float = 1e-3, lr: float = 0.5,
+                     iters: int = 200) -> jax.Array:
+    """The city fleet's trainer: :func:`_train_svm` for every DC at once,
+    the DCs on the minor (lane) axis.
+
+    x: (K, F, L); y: (K, L) int labels; mask: (K, L) {0,1}, an all-zero
+    column a padding or dead DC. Returns (C, F+1, L), the bias at F.
+
+    Each DC's model is only ``K x F x C`` products, so a batched matmul
+    fills 7 of the TPU's 128 lanes. On a TPU both contractions are
+    elementwise products summed over the non-lane axes, in one Pallas
+    kernel that keeps each DC block's steps in VMEM
+    (:mod:`repro.kernels.svm_lanes`), its operands rounded to bfloat16 as
+    the TPU's default precision rounds a float32 matmul. Elsewhere it is
+    ``vmap(_train_svm)``, which computes a float32 matmul as a CPU does."""
+    if jax.default_backend() == "tpu":
+        return _svm_lanes_pallas(x, y, mask, num_classes=num_classes,
+                                 lam=lam, lr=lr, iters=iters)
+    w = jax.vmap(lambda xi, yi, mi: _train_svm(
+        xi, yi, mi, num_classes=num_classes, lam=lam, lr=lr, iters=iters),
+        in_axes=(2, 1, 1))(x, y, mask)                    # (L, F+1, C)
+    return jnp.transpose(w, (2, 1, 0))
+
+
+def _svm_lanes_pallas(x, y, mask, *, num_classes: int, lam: float,
+                      lr: float, iters: int, interpret: bool = False):
+    """:func:`_train_svm_lanes` through the kernel; the data is rounded to
+    bfloat16 once, here, and stored so."""
+    denom = jnp.maximum(1.0, jnp.sum(mask, axis=0))
+    W, b = svm_lanes(x.astype(jnp.bfloat16), y.astype(jnp.int32),
+                     mask / denom, _rate(jnp.arange(iters), lr, iters),
+                     num_classes=num_classes, lam=lam, interpret=interpret)
+    return jnp.concatenate([W, b[:, None]], axis=1)
 
 
 @count_dispatch("train_svm")

@@ -149,6 +149,90 @@ def test_city_sharded_bitwise_matches_unsharded_subprocess():
 
 
 # ---------------------------------------------------------------------------
+# the city trainer (DESIGN.md §10): every DC's SVM at once, the DCs on the
+# lane axis; off the TPU the vmapped trainer, on it the Pallas kernel (here
+# in interpret mode)
+# ---------------------------------------------------------------------------
+
+def _lane_case(fleet, K=14, seed=0):
+    """Random per-DC data at ``fleet`` DCs: every 7th DC all masked (a
+    padding or dead DC), every 7th from the 4th half masked (``half_obs``).
+    Returns the fleet-major arrays and their lane-major (K, F, L) view."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(fleet, K, 54)).astype(np.float32)
+    y = rng.integers(0, 7, size=(fleet, K)).astype(np.int32)
+    m = np.ones((fleet, K), np.float32)
+    m[::7] = 0.0
+    m[3::7, 1::2] = 0.0
+    return (x, y, m), (x.transpose(1, 2, 0), y.T, m.T)
+
+
+def _lane_kernel(iters=20):
+    import jax
+    from repro.core import svm
+
+    return jax.jit(lambda x, y, m: svm._svm_lanes_pallas(
+        x, y, m, num_classes=7, lam=1e-3, lr=0.5, iters=iters,
+        interpret=True))
+
+
+@pytest.mark.parametrize("fleet", [128, 200, 256])
+def test_lane_trainer_matches_the_vmapped_trainer(fleet):
+    """Off the TPU the lane-major trainer gives every DC the bits that
+    ``vmap(_train_svm)`` (the scan engine's trainer) gives it, at a lane
+    multiple and off one; masked DCs stay zero."""
+    import jax
+    from repro.core import svm
+
+    fleet_major, lanes = _lane_case(fleet)
+    want = np.asarray(jax.jit(jax.vmap(lambda a, b, c: svm._train_svm(
+        a, b, c, num_classes=7, iters=20)))(*fleet_major))
+    want = want.transpose(2, 1, 0)                        # (C, F+1, L)
+    got = np.asarray(jax.jit(lambda x, y, m: svm._train_svm_lanes(
+        x, y, m, num_classes=7, iters=20))(*lanes))
+    np.testing.assert_array_equal(got, want)
+    assert not got[..., ::7].any()
+    assert np.abs(got[..., 3::7]).sum() > 0               # half masked
+
+
+@pytest.mark.parametrize("parts", [((0, 128), (128, 256)), ((0, 200),)],
+                         ids=["slices", "partial"])
+def test_lane_trainer_dc_model_is_the_same_in_any_fleet(parts):
+    """The kernel gives a DC the same bits trained inside a 256-DC fleet
+    or in a part of it: two 128-DC slices, or the first 200 DCs, whose
+    last block is partial. What shard-count invariance rests on."""
+    _, (x, y, m) = _lane_case(256, seed=1)
+    train = _lane_kernel()
+    whole = np.asarray(train(x, y, m))
+    for lo, hi in parts:
+        part = np.asarray(train(x[..., lo:hi], y[:, lo:hi], m[:, lo:hi]))
+        np.testing.assert_array_equal(whole[..., lo:hi], part)
+
+
+@pytest.mark.parametrize("fleet", [8, 32, 128, 200, 256])
+def test_lane_kernel_rounds_as_the_tpu_default_precision(fleet):
+    """The kernel, as it runs on a TPU, is the stated precision:
+    ``plain.svm_train`` at ``tpu_default`` (bfloat16 product operands,
+    float32 products and sums) to 1e-5, and visibly not float32
+    throughout; at 200 DCs its last block is partial, and its masked DCs
+    stay zero. ``W`` reaches the gradient only through which margins are
+    active; at 32 DCs (seed 3) its rounding flips one, so an unrounded
+    ``W`` fails here too."""
+    from bench.references import plain
+
+    (x, y, m), lanes = _lane_case(fleet, seed=3)
+    got = np.asarray(_lane_kernel()(*lanes), np.float64).transpose(2, 1, 0)
+    tpu = plain.svm_train(x, y, m, classes=7, iters=20,
+                          num=plain.TPU_DEFAULT)
+    f32 = plain.svm_train(x, y, m, classes=7, iters=20,
+                          num=plain.NUMS["float32"])
+    gap = lambda a: np.linalg.norm(got - a) / np.linalg.norm(a)   # noqa: E731
+    assert gap(tpu) <= 1e-5
+    assert gap(f32) >= 1e-3
+    assert not got[::7].any()
+
+
+# ---------------------------------------------------------------------------
 # the fleet-wide source screen (DESIGN.md §10): the pool is the M_CAP best
 # candidates by GreedyTL's own first-step objective, never the centre, a
 # dead DC or padding; and the city run matches the plain reference

@@ -22,6 +22,10 @@ import pytest
 REPO = os.path.join(os.path.dirname(__file__), "..")
 AUTOTUNE = os.path.join(REPO, "results", "benchmarks", "kernel_autotune.json")
 V5E_USABLE_HBM = 15.75e9          # of 16 GB, as XLA reports for one v5e
+# the city-250k round with the DCs on the lane axis plans 1.62e9 bytes
+# (1615564288); with the per-DC (55, 7) tiles of a vmapped trainer it
+# planned 9.53e9. The bound leaves that plan a quarter of room.
+CITY_CELL_ROUND_PLAN = 2.0e9
 BLOCK_RS = (64, 128, 256, 512)
 
 
@@ -84,11 +88,19 @@ def test_city_round_fits_one_v5e(one_chip):
 
 def test_city_cell_round_fits_one_v5e(one_chip):
     """The ``city-250k`` configuration's round (250,000 DCs, 14
-    observations each, 200 SVM iterations) fits one chip's memory."""
-    _city_round_fits(one_chip, K=14, iters=200)
+    observations each, 200 SVM iterations) fits one chip's memory, and
+    plans no more than the lane-major layout needs: the per-DC SVMs are
+    one kernel, not a vmapped batch of padded (55, 7) tiles."""
+    compiled, total = _city_round_fits(one_chip, K=14, iters=200)
+    assert total <= CITY_CELL_ROUND_PLAN, f"{total / 1e9:.2f} GB planned"
+    assert "svm_lanes" in compiled.as_text()
 
 
 def _city_round_fits(one_chip, K: int, iters: int):
+    """Compile the round as a TPU lowers it: the program asks
+    ``jax.default_backend()`` for its TPU path, which a described chip
+    does not answer, so the test answers it; GreedyTL's trial scorer is
+    held to its jnp form, so that no autotuner runs here."""
     from repro.core.cityscan import _city_round_program, city_fleet_pad
     from repro.data.synthetic_covtype import NUM_CLASSES, make_covtype_like
 
@@ -100,13 +112,17 @@ def _city_round_fits(one_chip, K: int, iters: int):
             s((L, K), jnp.int32), s((L, K)), s((L,), jnp.bool_),
             s((L,), jnp.int32), s(()), s((n_test, F)),
             s((n_test, NUM_CLASSES)))
-    compiled = _city_round_program(NUM_CLASSES, iters).lower(
-        *args).compile()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        mp.setenv("REPRO_KERNEL_FORCE", "jnp")
+        compiled = _city_round_program.__wrapped__(NUM_CLASSES, iters).lower(
+            *args).compile()
     mem = compiled.memory_analysis()
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes - mem.alias_size_in_bytes
              + mem.generated_code_size_in_bytes)
     assert total <= V5E_USABLE_HBM, f"{total / 1e9:.2f} GB per chip"
+    return compiled, total
 
 
 def _lm_kernel_case(name, sharding):
