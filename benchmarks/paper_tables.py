@@ -14,9 +14,12 @@ allocation and aggregation settings — derived from ``host_side`` field
 metadata) runs in lockstep on a shared fleet axis, so the sweep pays
 O(sample buckets) jitted dispatches per window for a whole table column
 group instead of O(rows x seeds).
+
+    PYTHONPATH=src python -m benchmarks.paper_tables [--quick]
 """
 from __future__ import annotations
 
+import argparse
 import json
 import os
 import time
@@ -64,3 +67,17 @@ def run_all(windows: int = 100, n_seeds: int = 3, quick: bool = False,
     with open(os.path.join(RESULTS_DIR, "paper_tables.json"), "w") as f:
         json.dump(out, f, indent=1)
     return out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true",
+                    help="30 windows and one seed instead of 100 and three")
+    args = ap.parse_args()
+    from repro.core.compile_cache import use_compile_cache
+    use_compile_cache()
+    run_all(quick=args.quick)
+
+
+if __name__ == "__main__":
+    main()

@@ -280,7 +280,6 @@ def main(argv=None) -> int:
 
     devices = require_tpu(args.chips)
     from repro.core.compile_cache import use_compile_cache
-    from repro.kernels.ops import autotune_table
 
     print(f"compile cache: {use_compile_cache()}")
     print(f"devices: {len(devices)} x {devices[0].device_kind}")
@@ -307,9 +306,6 @@ def main(argv=None) -> int:
         print(f"sweep service, smoke preset twice: {json.dumps(service)}")
     for d in devices[:args.chips]:
         print(f"memory_stats device {d.id}: {json.dumps(d.memory_stats())}")
-    for key, e in autotune_table().items():
-        print(f"loo_trials {key}: {e['impl']} block_r={e['block_r']} "
-              f"timings_us={json.dumps(e['timings_us'])}")
     print(json.dumps({"ok": True, "device": {
         "platform": devices[0].platform, "kind": devices[0].device_kind,
         "count": len(devices)}}))

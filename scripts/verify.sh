@@ -1,14 +1,13 @@
 #!/usr/bin/env bash
-# Tier-1 verification: test suite + parity/fault gates + benchmark smoke.
+# Tier-1 verification: test suite + parity/fault gates.
 #
-# Usage: scripts/verify.sh [--fast] [--units|--gates|--bench]
+# Usage: scripts/verify.sh [--fast] [--units|--gates]
 #   --fast    deselect @slow tests
 #   --units   only the unit/property test pass (gate files excluded —
 #             they run once, in the gates phase, not twice)
 #   --gates   only the explicit CI gates (dispatch/experiment/parallel/
 #             launcher suites + the parity and fault-injection scripts)
-#   --bench   only the benchmark smoke
-# Default (no phase flag) runs all three phases in order. The CI matrix
+# Default (no phase flag) runs both phases in order. The CI matrix
 # (.github/workflows/ci.yml) runs the phases as parallel jobs.
 set -euo pipefail
 cd "$(dirname "$0")/.."
@@ -20,7 +19,7 @@ MARK=()
 for arg in "$@"; do
     case "$arg" in
         --fast) MARK=(-m "not slow") ;;
-        --units|--gates|--bench) MODE="${arg#--}" ;;
+        --units|--gates) MODE="${arg#--}" ;;
         *) echo "unknown argument: $arg" >&2; exit 2 ;;
     esac
 done
@@ -90,8 +89,4 @@ if [[ "$MODE" == "all" || "$MODE" == "gates" ]]; then
     # frontier exactly, and the frontier metrics are bitwise a plain
     # SweepSpec.run of the frontier configs (DESIGN.md §14)
     python scripts/pareto_smoke.py --windows 6 --seeds 1
-fi
-
-if [[ "$MODE" == "all" || "$MODE" == "bench" ]]; then
-    python -m benchmarks.run --quick --skip-tables
 fi

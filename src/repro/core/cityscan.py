@@ -446,7 +446,7 @@ def city_fleet_pad(fleet_size: int) -> int:
 def _screen_scores(base, cx, cy, *, num_classes: int,
                    lam_src: float = 0.1, lam_bias: float = 2.0):
     """GreedyTL's first-step objective for every DC's base model as a
-    source (``_greedy_select_incremental``'s trial score of candidate j at
+    source (``greedytl._greedy_select``'s trial score of candidate j at
     the bias-only active set), on the centre's ``K`` observations ``cx``,
     ``cy``. The bias-only factor is ``sqrt(K + lam_bias + 1e-4) I``, so
     the bordering identity reduces to per-DC sums over the ``R = K*C``

@@ -4,9 +4,9 @@ The cache key includes its directory, so a directory that moves between
 runs never hits. ``JAX_COMPILATION_CACHE_DIR`` places the cache from
 outside; without it, the cache lives at the fixed ``<repo>/.jax_cache``
 (git-ignored). Entry points (``chip_smoke.py``, the sweep server, the
-launcher worker, the benchmark driver) call :func:`use_compile_cache`;
-library imports never do, so the test suite runs without a cache unless
-the variable is set.
+launcher worker, the benchmark cells, the paper tables) call
+:func:`use_compile_cache`; library imports never do, so the test suite
+runs without a cache unless the variable is set.
 """
 from __future__ import annotations
 

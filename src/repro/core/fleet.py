@@ -32,7 +32,8 @@ host-side control flow stay exactly as in the unstacked runners.
 
 Election/subsampling policies are resolved through the :mod:`~repro.core.
 htl` module at call time, so policy ablations that monkey-patch the loop
-engine (benchmarks/ablations.py) apply to this engine too.
+engine (the election ablation of benchmarks/ablations.py) apply to this
+engine too.
 """
 from __future__ import annotations
 

@@ -32,8 +32,11 @@ masked Cholesky factor G = LLᵀ of the column-masked Gram system — never
 ``jnp.linalg.inv``. Trial scoring in the greedy loop reuses the factor of
 the *current* active set across all M candidates via the bordering identity
 (Schur complement of the added row/column), which drops per-candidate cost
-from O(D³) to O(D²) and collapses the whole trial sweep into one fused
-kernel launch (``repro.kernels.loo_trials``; pure-jnp fallback on CPU).
+from O(D³) to O(D²) and collapses the whole trial sweep into one
+(R,D)x(D,M) product and an elementwise epilogue (:func:`loo_trials`, plain
+jnp that XLA fuses). The Pallas kernel of the same contract
+(``repro.kernels.loo_trials``) lost to it at every shape on a TPU v5e and
+is not on this path.
 
 **Incremental factor carry (DESIGN.md §11).** The greedy loop never
 refactorizes at all: the factor of the active set is carried ACROSS
@@ -47,8 +50,8 @@ All carries are fixed-shape (padded to C + min(k_max, M) active slots), so
 ``lax.scan``/``shard_map`` engines compile the loop once. The final
 coefficients still come from one full masked factorization of the selected
 set (one per call, as before), so downstream numerics are unchanged by the
-carry. ``incremental=False`` keeps the PR-2 refactorize-per-step loop as
-the in-tree oracle for property tests and the before/after benchmark.
+carry. The tests hold the carried loop to a float64 host loop that
+refactorizes at every step (``kernels/ref.greedy_select_refactor_reference``).
 """
 from __future__ import annotations
 
@@ -60,7 +63,6 @@ from jax.scipy.linalg import solve_triangular
 
 from repro.core.dispatch import count_dispatch
 from repro.core.svm import svm_scores
-from repro.kernels import ops as kernel_ops
 
 
 def _chol_masked(AtA, lam_d, cmask):
@@ -107,6 +109,23 @@ def _loo_ridge(A, y, rmask, cmask, lam):
                            rmask, cmask, lam_d)
 
 
+def loo_trials(ut, cc, a_cand, fitted_base, h_base, y, rmask, zj, dinv):
+    """GreedyTL's trial scorer: the LOO SSE of every bordered candidate.
+
+    ut: (R, D) whitened rows of the active set; cc: (D, M) candidate
+    borderings; a_cand: (R, M) candidate columns; fitted_base/h_base/y/rmask:
+    (R,); zj/dinv: (M,) bordered RHS and inverse Schur pivots. Each trial is
+    a rank-1 bordering t_ij = (A_ij − u_iᵀc_j)·d_j⁻¹ of the shared fit and
+    leverage. Returns (M,).
+    """
+    t = (a_cand - ut @ cc) * dinv[None, :]                       # (R, M)
+    fitted = fitted_base[:, None] + t * zj[None, :]
+    resid = (fitted - y[:, None]) * rmask[:, None]
+    h = h_base[:, None] + t * t
+    loo = resid / jnp.maximum(1.0 - h, 0.1)
+    return jnp.sum(loo * loo, axis=0)
+
+
 def _score_trials(AtA, Aty, A_rm, y, rmask, cmask, lam_d, M):
     """LOO SSE of every candidate bordering j < M of the active set cmask.
 
@@ -114,7 +133,7 @@ def _score_trials(AtA, Aty, A_rm, y, rmask, cmask, lam_d, M):
     the bordering identity: with c_j = L⁻¹g_j and Schur pivot
     d_j² = (G_jj + λ_j) − ‖c_j‖², the bordered factor extends every shared
     solve by one entry — t_ij = (A_ij − u_iᵀc_j)/d_j — so leverage and fit
-    update by rank 1 per row. The (R,M) sweep runs as one fused kernel.
+    update by rank 1 per row; :func:`loo_trials` sweeps all (R,M) trials.
     Candidates already active (or masked) get finite garbage here; the
     greedy loop overwrites them with +inf.
     """
@@ -132,42 +151,12 @@ def _score_trials(AtA, Aty, A_rm, y, rmask, cmask, lam_d, M):
     # to +inf by the greedy body before argmin)
     dinv = jax.lax.rsqrt(jnp.maximum(dsq, 1e-8)) * (1.0 - cmask[:M])
     zj = (Aty[:M] - Cc.T @ z) * dinv
-    return kernel_ops.loo_trials(Ut, Cc, A_rm[:, :M], fitted_base, h_base,
-                                 y, rmask, zj, dinv)
+    return loo_trials(Ut, Cc, A_rm[:, :M], fitted_base, h_base, y, rmask,
+                      zj, dinv)
 
 
-def _greedy_select_refactor(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
-                            M: int, C: int, k_max: int):
-    """PR-2 greedy source selection: full masked refactorization per step
-    (``_score_trials`` re-factors the active system on every accepted
-    candidate). Kept verbatim as the in-tree oracle for the incremental
-    carry (property tests + before/after benchmark). Returns (sel, best)."""
-    bias_cols = jnp.concatenate([jnp.zeros(M), jnp.ones(C)])
-
-    def cond(state):
-        k, sel, best, done = state
-        return (~done) & (k < min(k_max, M))
-
-    def body(state):
-        k, sel, best, done = state
-        cm = jnp.concatenate([sel * src_mask, jnp.ones(C)])
-        objs = _score_trials(AtA, Aty, A_rm, yr, rmask, cm, lam_d, M)
-        objs = jnp.where((sel > 0) | (src_mask == 0), jnp.inf, objs)
-        j = jnp.argmin(objs)
-        improved = (objs[j] < best) & ~done
-        sel = jnp.where(improved, jnp.where(jnp.arange(M) == j, 1.0, sel),
-                        sel)
-        return (k + 1, sel, jnp.where(improved, objs[j], best),
-                done | ~improved)
-
-    obj0, _ = _loo_ridge_chol(AtA, Aty, A_rm, yr, rmask, bias_cols, lam_d)
-    _, sel, best, _ = jax.lax.while_loop(
-        cond, body, (0, jnp.zeros(M), obj0, jnp.asarray(False)))
-    return sel, best
-
-
-def _greedy_select_incremental(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
-                               M: int, C: int, k_max: int):
+def _greedy_select(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
+                   M: int, C: int, k_max: int):
     """Greedy source selection with the factorization CARRIED across steps.
 
     The active set's Cholesky factor is maintained in acceptance-permuted
@@ -182,7 +171,7 @@ def _greedy_select_incremental(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
 
     with Dk = C + min(k_max, M). Per step, the Schur pivots d_j and
     bordered RHS z_j come straight from the carries (no factorization), the
-    M-candidate sweep runs as one ``loo_trials`` kernel launch, and
+    M-candidate sweep is one :func:`loo_trials` call, and
     accepting j appends the bordering column t_j = (A_:j − Ut c_j)/d_j to
     ``Ut``, the row (G_j: − c_jᵀCc)/d_j to ``Cc``, and z_j to ``z`` — the
     exact forward-substitution rows a from-scratch factor of the grown set
@@ -218,8 +207,8 @@ def _greedy_select_incremental(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
         dsq = diagG - jnp.sum(Cc ** 2, axis=0)
         dinv = jax.lax.rsqrt(jnp.maximum(dsq, 1e-8)) * (1.0 - active)
         zj = (Aty[:M] - Cc.T @ z) * dinv
-        objs = kernel_ops.loo_trials(Ut, Cc, A_rm[:, :M], fitted, h, yr,
-                                     rmask, zj, dinv)
+        objs = loo_trials(Ut, Cc, A_rm[:, :M], fitted, h, yr, rmask, zj,
+                          dinv)
         objs = jnp.where((sel > 0) | (src_mask == 0), jnp.inf, objs)
         j = jnp.argmin(objs)
         improved = (objs[j] < best) & ~done
@@ -247,8 +236,7 @@ def _greedy_select_incremental(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d, *,
 
 def _greedytl(x, y, mask, src_w, src_mask, *, num_classes: int,
               lam_src: float = 0.1, lam_x: float = 10.0,
-              lam_bias: float = 2.0, k_max: int = 16,
-              incremental: bool = True):
+              lam_bias: float = 2.0, k_max: int = 16):
     """Unjitted GreedyTL core — also the map target of the fleet refiner."""
     n, F = x.shape
     M, _, C = src_w.shape
@@ -279,13 +267,10 @@ def _greedytl(x, y, mask, src_w, src_mask, *, num_classes: int,
 
     # Early-exit greedy selection: once no trial improves, further steps are
     # provable no-ops, so a while_loop saves the (typically ~4x) dead steps
-    # a fixed-length scan would still execute. The incremental path carries
-    # the active-set factor across accepted steps; the refactorizing path is
-    # the PR-2 oracle.
-    select = (_greedy_select_incremental if incremental
-              else _greedy_select_refactor)
-    sel, _ = select(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d,
-                    M=M, C=C, k_max=k_max)
+    # a fixed-length scan would still execute. The loop carries the
+    # active-set factor across accepted steps.
+    sel, _ = _greedy_select(AtA, Aty, A_rm, yr, rmask, src_mask, lam_d,
+                            M=M, C=C, k_max=k_max)
 
     cm = jnp.concatenate([sel * src_mask, jnp.ones(C)])
     # one full factorization of the SELECTED set per call (not per step)
@@ -315,29 +300,26 @@ def _greedytl(x, y, mask, src_w, src_mask, *, num_classes: int,
 
 
 @count_dispatch("greedytl")
-@partial(jax.jit, static_argnames=("num_classes", "k_max", "incremental"))
+@partial(jax.jit, static_argnames=("num_classes", "k_max"))
 def greedytl(x, y, mask, src_w, src_mask, *, num_classes: int,
              lam_src: float = 0.1, lam_x: float = 10.0,
-             lam_bias: float = 2.0, k_max: int = 16,
-             incremental: bool = True):
+             lam_bias: float = 2.0, k_max: int = 16):
     """Greedy source combination + gated local correction (see module doc).
 
     x: (n, F) padded local data; y: (n,); mask: (n,) row validity.
     src_w: (M, F+1, C) stacked source hypotheses; src_mask: (M,).
     Returns (w_eff (F+1, C), selected (M,) 0/1 source-selection mask).
-    ``incremental=False`` selects the PR-2 refactorize-per-step oracle.
     """
     return _greedytl(x, y, mask, src_w, src_mask, num_classes=num_classes,
                      lam_src=lam_src, lam_x=lam_x, lam_bias=lam_bias,
-                     k_max=k_max, incremental=incremental)
+                     k_max=k_max)
 
 
 @count_dispatch("greedytl_fleet")
-@partial(jax.jit, static_argnames=("num_classes", "k_max", "incremental"))
+@partial(jax.jit, static_argnames=("num_classes", "k_max"))
 def greedytl_fleet(x, y, mask, src_w, src_mask, *, num_classes: int,
                    lam_src: float = 0.1, lam_x: float = 10.0,
-                   lam_bias: float = 2.0, k_max: int = 16,
-                   incremental: bool = True):
+                   lam_bias: float = 2.0, k_max: int = 16):
     """GreedyTL at every DC of a padded fleet — ONE dispatch per window.
 
     x: (L, cap, F); y: (L, cap); mask: (L, cap). The source pool
@@ -355,17 +337,15 @@ def greedytl_fleet(x, y, mask, src_w, src_mask, *, num_classes: int,
     return jax.lax.map(
         lambda t: _greedytl(t[0], t[1], t[2], src_w, src_mask,
                             num_classes=num_classes, lam_src=lam_src,
-                            lam_x=lam_x, lam_bias=lam_bias, k_max=k_max,
-                            incremental=incremental),
+                            lam_x=lam_x, lam_bias=lam_bias, k_max=k_max),
         (x, y, mask))
 
 
 @count_dispatch("greedytl_fleet_stacked")
-@partial(jax.jit, static_argnames=("num_classes", "k_max", "incremental"))
+@partial(jax.jit, static_argnames=("num_classes", "k_max"))
 def greedytl_fleet_stacked(x, y, mask, src_w, src_mask, *, num_classes: int,
                            lam_src: float = 0.1, lam_x: float = 10.0,
-                           lam_bias: float = 2.0, k_max: int = 16,
-                           incremental: bool = True):
+                           lam_bias: float = 2.0, k_max: int = 16):
     """GreedyTL over a fleet where every DC carries its OWN source pool.
 
     Seed-stacked variant of :func:`greedytl_fleet`: several scenario
@@ -382,6 +362,5 @@ def greedytl_fleet_stacked(x, y, mask, src_w, src_mask, *, num_classes: int,
     return jax.lax.map(
         lambda t: _greedytl(t[0], t[1], t[2], t[3], t[4],
                             num_classes=num_classes, lam_src=lam_src,
-                            lam_x=lam_x, lam_bias=lam_bias, k_max=k_max,
-                            incremental=incremental),
+                            lam_x=lam_x, lam_bias=lam_bias, k_max=k_max),
         (x, y, mask, src_w, src_mask))

@@ -18,10 +18,11 @@ elementwise epilogue and a row reduction, fused here into a single kernel
 launch over row tiles (grid is sequential; a VMEM scratch accumulates the
 per-candidate objectives across tiles).
 
-``loo_trials_ref`` is the pure-jnp oracle; on CPU backends it IS the
-production path (see ``repro.kernels.ops``) — interpret mode is only for
-kernel-correctness tests, Python-per-grid-cell is far too slow for the
-greedy loop.
+The program scores its trials with the jnp form of the same contract,
+``repro.core.greedytl.loo_trials``, which XLA fuses; on a TPU v5e every
+tile of this kernel was slower than it, so no program code calls the
+kernel. tests/test_kernels.py holds the kernel to that scorer and
+tests/test_tpu_compile.py compiles it for a described v5e.
 """
 from __future__ import annotations
 
@@ -32,20 +33,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 MAX_CANDIDATES = 128      # one lane tile; M_CAP=16 in the HTL layer
-
-
-def loo_trials_ref(ut, cc, a_cand, fitted_base, h_base, y, rmask, zj, dinv):
-    """Pure-jnp oracle (and the CPU production path).
-
-    ut: (R, D); cc: (D, M); a_cand: (R, M); fitted_base/h_base/y/rmask: (R,);
-    zj/dinv: (M,). Returns per-candidate LOO SSE (M,).
-    """
-    t = (a_cand - ut @ cc) * dinv[None, :]                       # (R, M)
-    fitted = fitted_base[:, None] + t * zj[None, :]
-    resid = (fitted - y[:, None]) * rmask[:, None]
-    h = h_base[:, None] + t * t
-    loo = resid / jnp.maximum(1.0 - h, 0.1)
-    return jnp.sum(loo * loo, axis=0)
 
 
 def _loo_trials_kernel(ut_ref, cc_ref, ac_ref, fb_ref, hb_ref, y_ref,
@@ -79,7 +66,7 @@ def _round_up(x: int, m: int) -> int:
 @functools.partial(jax.jit, static_argnames=("block_r", "interpret"))
 def loo_trials(ut, cc, a_cand, fitted_base, h_base, y, rmask, zj, dinv, *,
                block_r: int = 256, interpret: bool = False):
-    """Pallas trial scorer; same contract as :func:`loo_trials_ref`.
+    """Pallas trial scorer; same contract as ``greedytl.loo_trials``.
 
     Row-padding is handled here (padded rows carry rmask=0, so they add 0 to
     every objective); candidate masking (already-selected / invalid columns)

@@ -61,8 +61,9 @@ def loo_trials_inv_reference(AtA, Aty, A_rm, y, rmask, cmask, lam_d, M):
     """Inverse-based greedy-trial scorer — the O(M·D³) formulation the
     Cholesky-bordering kernel replaces. For each candidate column j < M it
     solves the column-masked ridge over active ∪ {j} via ``jnp.linalg.inv``
-    and returns the closed-form LOO SSE (M,). Ground truth for
-    ``loo_trials`` / ``loo_trials_ref`` parity tests.
+    and returns the closed-form LOO SSE (M,). Ground truth for the
+    trial scorers' parity tests (``greedytl.loo_trials`` and the Pallas
+    ``kernels.loo_trials``).
     """
     def one(j):
         cm = jnp.where(jnp.arange(cmask.shape[0]) == j, 1.0, cmask)

@@ -1,7 +1,7 @@
 """What keeps the main path honest on a TPU, checked on the CPU: the
-compile-cache placement, the one-process-per-chip guard, the autotuner's
-refusal to hide a failing kernel, and chip_smoke.py's phases at tiny
-sizes (the script itself refuses to run without a TPU)."""
+compile-cache placement, the one-process-per-chip guard, and
+chip_smoke.py's phases at tiny sizes (the script itself refuses to run
+without a TPU)."""
 import json
 import os
 import subprocess
@@ -16,7 +16,6 @@ from repro.core.experiment import get_preset
 from repro.core.launcher import HostsExecutor
 from repro.core.parallel import ChipHeldError, get_executor
 from repro.data.synthetic_covtype import make_covtype_like
-from repro.kernels import ops as kernel_ops
 
 REPO = os.path.join(os.path.dirname(__file__), "..")
 DATA = make_covtype_like(seed=0)
@@ -68,27 +67,6 @@ def test_child_interpreter_backends_refuse_a_held_chip(monkeypatch,
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     with pytest.raises(ChipHeldError, match="one process"):
         executor().execute(labels, cfgs, DATA, stack=True)
-
-
-# ---------------------------------------------------------------------------
-# the autotuner never hides a kernel that fails to compile
-# ---------------------------------------------------------------------------
-
-def test_autotune_raises_on_a_failing_candidate(monkeypatch):
-    kernel_ops.reset_autotune_cache()
-
-    def refuse(fn, args, reps):
-        raise RuntimeError("Mosaic refused the kernel")
-
-    monkeypatch.setattr(kernel_ops, "_time_call", refuse)
-    with pytest.raises(RuntimeError, match="Mosaic refused"):
-        kernel_ops.autotune_loo_trials(300, 23, 16, backend="tpu",
-                                       candidates=[("pallas", 64)])
-    assert "R512_D23_M16" not in kernel_ops.autotune_table("tpu")
-    with pytest.raises(ValueError, match="at least one candidate"):
-        kernel_ops.autotune_loo_trials(300, 23, 16, backend="tpu",
-                                       candidates=[])
-    kernel_ops.reset_autotune_cache()
 
 
 # ---------------------------------------------------------------------------

@@ -3,7 +3,7 @@ collection / learning / energy pipeline turns into a red test.
 
 tests/golden/smoke_golden.json pins known-good smoke-preset values
 (per-label converged F1, mean F1 curves, energy totals by purpose — the
-quantities behind the paper tables and results/benchmarks/sweep_api.json).
+quantities behind the paper tables).
 A failure here means the published numbers changed: either fix the
 regression, or — for an *intentional* numeric change — regenerate the
 fixture and say so in the PR:

@@ -1,13 +1,13 @@
 """Incremental-factor GreedyTL: property suite against the
-full-refactorization oracle, plus kernel-selection (autotuner /
-REPRO_KERNEL_FORCE) contracts. DESIGN.md §11.
+full-refactorization oracle. DESIGN.md §11.
 
 The carry contract: the greedy loop extends the active set's Cholesky
 factor by the bordering column computed during trial scoring instead of
-refactorizing, so selections must match the PR-2 refactorize-per-step path
-and the final model must agree ≤ 1e-5 (it is in fact computed by the same
-final full factorization of the selected set, so equal selections give
-bit-equal downstream numerics).
+refactorizing, so on the same Stage-1 system it must select the sources
+that a float64 loop refactorizing at every step selects
+(``kernels/ref.greedy_select_refactor_reference``), with the same LOO
+objective. The model follows from the selection through one final full
+factorization of the selected set, so equal selections give equal models.
 """
 import numpy as np
 import pytest
@@ -19,25 +19,11 @@ except ImportError:   # deterministic shim, tests/_hypothesis_fallback.py
 
 import jax.numpy as jnp
 
-from repro.core.greedytl import (_greedy_select_incremental,
-                                 _greedy_select_refactor, greedytl,
-                                 greedytl_fleet, greedytl_fleet_stacked)
-from repro.kernels import ops as kernel_ops
-from repro.kernels.loo_trials import loo_trials_ref
+from repro.core.greedytl import (_greedy_select, greedytl, greedytl_fleet,
+                                 greedytl_fleet_stacked)
 from repro.kernels.ref import greedy_select_refactor_reference
 
 F, C, M_CAP = 54, 7, 16
-
-
-@pytest.fixture(autouse=True)
-def _isolated_kernel_selection(tmp_path, monkeypatch):
-    """Every test here runs with a private autotune cache dir and no forced
-    kernel, and leaves the process-global cache clean afterwards."""
-    monkeypatch.setenv(kernel_ops.CACHE_DIR_ENV, str(tmp_path))
-    monkeypatch.delenv(kernel_ops.FORCE_ENV, raising=False)
-    kernel_ops.reset_autotune_cache()
-    yield
-    kernel_ops.reset_autotune_cache()
 
 
 # ---------------------------------------------------------------------------
@@ -96,6 +82,45 @@ def _random_stacked_system(M, rows, seed, p_src=0.8, p_row=0.85):
             lam_d)
 
 
+def _stage1_system(x, y, mask, src_w, src_mask, lam_src=0.1, lam_bias=2.0):
+    """GreedyTL's Stage-1 system for one local dataset, built in float64
+    from the definition (unit-RMS source predictions and per-class biases
+    over the n*C stacked one-vs-rest rows), in the layout of
+    :func:`_random_stacked_system`."""
+    x, mask, src_w = (np.asarray(v, np.float64) for v in (x, mask, src_w))
+    y = np.asarray(y)
+    n, M = len(y), src_w.shape[0]
+    xm = x * mask[:, None]
+    Yoh = (2.0 * np.eye(C)[y] - 1.0) * mask[:, None]
+    H = (np.einsum("nf,mfc->mnc", xm, src_w[:, :-1])
+         + src_w[:, None, -1]) * mask[None, :, None]
+    s = np.sqrt(np.sum(H ** 2, axis=(1, 2))
+                / (max(1.0, mask.sum()) * C)) + 1e-6
+    A = np.concatenate([(H / s[:, None, None]).transpose(1, 2, 0)
+                        .reshape(n * C, M), np.tile(np.eye(C), (n, 1))], 1)
+    yr, rmask = Yoh.reshape(-1), np.repeat(mask, C)
+    lam_d = np.concatenate([np.full(M, lam_src), np.full(C, lam_bias)]) + 1e-4
+    A_rm = A * rmask[:, None]
+    return (A_rm.T @ A_rm, A_rm.T @ (yr * rmask), A_rm, yr, rmask,
+            np.asarray(src_mask, np.float64), lam_d)
+
+
+def _assert_matches_oracle(sel, problem, k_max=16):
+    """``sel``, a program's selection on ``problem`` (x, y, mask, src_w,
+    src_mask), is the float64 refactorizing oracle's on the same Stage-1
+    system, and the carried loop reaches the oracle's LOO objective."""
+    system = _stage1_system(*problem)
+    m = system[5].shape[0]
+    sel_ref, traj = greedy_select_refactor_reference(*system, m, k_max=k_max)
+    assert np.array_equal(np.asarray(sel), sel_ref)
+    sel_inc, best = _greedy_select(
+        *(jnp.asarray(v, jnp.float32) for v in system), M=m, C=C,
+        k_max=k_max)
+    assert np.array_equal(np.asarray(sel_inc), sel_ref)
+    rel = abs(float(best) - traj[-1]) / max(abs(traj[-1]), 1e-6)
+    assert rel < 1e-5, rel
+
+
 # ---------------------------------------------------------------------------
 # tentpole: incremental carry == full refactorization
 # ---------------------------------------------------------------------------
@@ -109,18 +134,14 @@ def test_incremental_selection_matches_refactor_on_random_systems(
         seed, m, rows, k_max):
     """Property: on random masked Gram systems the carried-factor loop and
     the refactorize-per-step loop accept the same sources and report the
-    same objective (≤ 1e-5 rel)."""
-    AtA, Aty, A_rm, y, rmask, src_mask, lam_d = _random_stacked_system(
-        m, rows, seed)
-    args = tuple(jnp.asarray(v) for v in
-                 (AtA, Aty, A_rm, y, rmask, src_mask, lam_d))
-    sel_inc, best_inc = _greedy_select_incremental(*args, M=m, C=C,
-                                                   k_max=k_max)
-    sel_ref, best_ref = _greedy_select_refactor(*args, M=m, C=C,
-                                                k_max=k_max)
-    assert np.array_equal(np.asarray(sel_inc), np.asarray(sel_ref))
-    rel = abs(float(best_inc) - float(best_ref)) / max(
-        abs(float(best_ref)), 1e-6)
+    same objective (≤ 1e-5 rel), at every greedy depth bound."""
+    system = _random_stacked_system(m, rows, seed)
+    sel_inc, best_inc = _greedy_select(
+        *(jnp.asarray(v) for v in system), M=m, C=C, k_max=k_max)
+    sel_ref, traj = greedy_select_refactor_reference(*system, m,
+                                                     k_max=k_max)
+    assert np.array_equal(np.asarray(sel_inc), sel_ref)
+    rel = abs(float(best_inc) - traj[-1]) / max(abs(traj[-1]), 1e-6)
     assert rel < 1e-5, rel
 
 
@@ -135,7 +156,7 @@ def test_incremental_matches_float64_inverse_oracle(seed, m, rows):
     oracle's own objectives for both choices must agree ≤ 1e-4."""
     AtA, Aty, A_rm, y, rmask, src_mask, lam_d = _random_stacked_system(
         m, rows, seed)
-    sel_inc, best_inc = _greedy_select_incremental(
+    sel_inc, best_inc = _greedy_select(
         *(jnp.asarray(v) for v in
           (AtA, Aty, A_rm, y, rmask, src_mask, lam_d)), M=m, C=C, k_max=16)
     sel_inc = np.asarray(sel_inc)
@@ -159,15 +180,11 @@ def test_incremental_matches_float64_inverse_oracle(seed, m, rows):
 @pytest.mark.parametrize("k_max", [1, 2, 4, 8, 12, 16])
 def test_depth_sweep_matches_refactor_path(k_max):
     """Greedy depths 1–16 (k_max-bounded on a deep-accepting problem): the
-    default incremental entry point equals the refactorizing oracle."""
-    x, y, m, src, sm = _deep_problem()
-    w_inc, sel_inc = greedytl(x, y, m, src, sm, num_classes=C, k_max=k_max)
-    w_ref, sel_ref = greedytl(x, y, m, src, sm, num_classes=C, k_max=k_max,
-                              incremental=False)
-    assert np.array_equal(np.asarray(sel_inc), np.asarray(sel_ref))
-    assert int(np.asarray(sel_inc).sum()) == min(k_max, 12)
-    np.testing.assert_allclose(np.asarray(w_inc), np.asarray(w_ref),
-                               rtol=1e-5, atol=1e-5)
+    entry point selects what the refactorizing oracle selects."""
+    problem = _deep_problem()
+    _, sel = greedytl(*problem, num_classes=C, k_max=k_max)
+    assert int(np.asarray(sel).sum()) == min(k_max, 12)
+    _assert_matches_oracle(sel, problem, k_max=k_max)
 
 
 @given(n=st.integers(min_value=4, max_value=60),
@@ -175,25 +192,22 @@ def test_depth_sweep_matches_refactor_path(k_max):
        seed=st.integers(min_value=0, max_value=100))
 @settings(max_examples=10, deadline=None)
 def test_random_problems_match_refactor_path(n, n_src, seed):
-    """Random (possibly degenerate) local datasets and source pools: same
-    selection, model ≤ 1e-5, through the public entry point."""
+    """Random (possibly degenerate) local datasets and source pools: the
+    oracle's selection and objective, through the public entry point."""
     rng = np.random.default_rng(seed)
     x = rng.normal(0, 1, (n, F)).astype(np.float32)
     y = rng.integers(0, C, n)
-    args = _pad_problem(x, y, n_src, seed)
-    w_inc, sel_inc = greedytl(*args, num_classes=C)
-    w_ref, sel_ref = greedytl(*args, num_classes=C, incremental=False)
-    assert np.array_equal(np.asarray(sel_inc), np.asarray(sel_ref))
-    np.testing.assert_allclose(np.asarray(w_inc), np.asarray(w_ref),
-                               rtol=1e-5, atol=1e-5)
+    problem = _pad_problem(x, y, n_src, seed)
+    _, sel = greedytl(*problem, num_classes=C)
+    _assert_matches_oracle(sel, problem)
 
 
 def test_all_fleet_entry_points_match_refactor_oracle():
     """greedytl / greedytl_fleet / greedytl_fleet_stacked on the deep
-    problem: every entry point defaults to the incremental carry, stays
-    bitwise equal across the lax.map variants, and agrees with the
-    refactorizing oracle ≤ 1e-5."""
-    x, y, m, src, sm = _deep_problem()
+    problem: every entry point stays bitwise equal across the lax.map
+    variants and selects what the refactorizing oracle selects."""
+    problem = _deep_problem()
+    x, y, m, src, sm = problem
     L = 3
     xf, yf, mf = (jnp.stack([v] * L) for v in (x, y, m))
     srcs, sms = (jnp.stack([v] * L) for v in (src, sm))
@@ -201,14 +215,12 @@ def test_all_fleet_entry_points_match_refactor_oracle():
     w1, s1 = greedytl(x, y, m, src, sm, num_classes=C)
     wf, sf = greedytl_fleet(xf, yf, mf, src, sm, num_classes=C)
     ws, ss = greedytl_fleet_stacked(xf, yf, mf, srcs, sms, num_classes=C)
-    w_ref, _ = greedytl(x, y, m, src, sm, num_classes=C, incremental=False)
     for i in range(L):
         assert np.array_equal(np.asarray(wf)[i], np.asarray(w1))
         assert np.array_equal(np.asarray(ws)[i], np.asarray(w1))
         assert np.array_equal(np.asarray(sf)[i], np.asarray(s1))
         assert np.array_equal(np.asarray(ss)[i], np.asarray(s1))
-    np.testing.assert_allclose(np.asarray(w1), np.asarray(w_ref),
-                               rtol=1e-5, atol=1e-5)
+    _assert_matches_oracle(s1, problem)
 
 
 def test_scan_engine_threads_incremental_carry():
@@ -224,97 +236,3 @@ def test_scan_engine_threads_incremental_carry():
     r_fleet = run_scenario(ScenarioConfig(engine="fleet", **kw), data)
     assert r_scan.f1_curve == r_fleet.f1_curve
     assert r_scan.ledger.total() == r_fleet.ledger.total()
-
-
-# ---------------------------------------------------------------------------
-# kernel selection: force override + autotuner cache
-# ---------------------------------------------------------------------------
-
-def _kernel_inputs(R=64, D=23, M=16, seed=3):
-    rng = np.random.default_rng(seed)
-    f32 = np.float32
-    return tuple(jnp.asarray(v) for v in (
-        rng.standard_normal((R, D)).astype(f32),
-        rng.standard_normal((D, M)).astype(f32),
-        rng.standard_normal((R, M)).astype(f32),
-        rng.standard_normal(R).astype(f32),
-        np.abs(rng.standard_normal(R)).astype(f32) * 0.1,
-        rng.standard_normal(R).astype(f32),
-        (rng.random(R) < 0.8).astype(f32),
-        rng.standard_normal(M).astype(f32),
-        np.abs(rng.standard_normal(M)).astype(f32),
-    ))
-
-
-def test_kernel_force_jnp_and_pallas_agree(monkeypatch):
-    """REPRO_KERNEL_FORCE=jnp and =pallas (interpret off-TPU) agree ≤ 1e-5
-    on the same inputs; jnp-forced output is exactly the reference."""
-    args = _kernel_inputs()
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "jnp")
-    out_jnp = np.asarray(kernel_ops.loo_trials(*args))
-    assert np.array_equal(out_jnp, np.asarray(loo_trials_ref(*args)))
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "pallas")
-    out_pal = np.asarray(kernel_ops.loo_trials(*args))
-    rel = np.max(np.abs(out_pal - out_jnp)) / (np.max(np.abs(out_jnp))
-                                               + 1e-9)
-    assert rel < 1e-5, rel
-
-
-def test_kernel_force_rejects_garbage(monkeypatch):
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "mosaic")
-    with pytest.raises(ValueError):
-        kernel_ops.loo_trials(*_kernel_inputs())
-
-
-def test_autotune_persists_and_reloads(monkeypatch, tmp_path):
-    """The autotuner measures candidates, persists the per-backend JSON
-    table, and a fresh process-state reloads it WITHOUT re-measuring."""
-    entry = kernel_ops.autotune_loo_trials(100, 23, 16, persist=True,
-                                           candidates=[("jnp", 0)], reps=1)
-    assert entry["impl"] == "jnp"
-    path = tmp_path / kernel_ops.CACHE_FILE
-    assert path.exists()
-    payload = __import__("json").loads(path.read_text())
-    import jax
-    backend = jax.default_backend()
-    key = kernel_ops.autotune_key(100, 23, 16)
-    assert key == "R128_D23_M16"
-    assert payload["backends"][backend][key]["timings_us"]["jnp"] >= 0
-
-    kernel_ops.reset_autotune_cache()      # simulate a fresh process
-    monkeypatch.setattr(kernel_ops, "_time_call",
-                        lambda *a, **k: pytest.fail("re-measured a shape "
-                                                    "already in the table"))
-    again = kernel_ops.autotune_loo_trials(100, 23, 16)
-    assert again == entry
-
-
-def test_autotuned_block_r_reaches_the_kernel(monkeypatch):
-    """A tuned non-default block_r is honored end to end: tune a tiny
-    Pallas tile, force the pallas path, and check parity with the
-    reference (exercises the small-R/odd-tile padding fix)."""
-    entry = kernel_ops.autotune_loo_trials(
-        64, 23, 16, candidates=[("pallas", 16)], reps=1)
-    assert entry == {"impl": "pallas", "block_r": 16,
-                     **{k: entry[k] for k in ("timings_us", "shape",
-                                              "reps")}}
-    args = _kernel_inputs(R=64)
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "pallas")
-    out = np.asarray(kernel_ops.loo_trials(*args))
-    ref = np.asarray(loo_trials_ref(*args))
-    rel = np.max(np.abs(out - ref)) / (np.max(np.abs(ref)) + 1e-9)
-    assert rel < 1e-5, rel
-
-
-def test_greedytl_result_is_invariant_to_kernel_selection(monkeypatch):
-    """End to end: a forced-jnp and a forced-pallas (interpret) greedy
-    refine agree ≤ 1e-5 on the deep problem — the selection layer may pick
-    either implementation without changing results."""
-    x, y, m, src, sm = _deep_problem(n=32, n_src=6)
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "jnp")
-    w_jnp, sel_jnp = greedytl(x, y, m, src, sm, num_classes=C)
-    monkeypatch.setenv(kernel_ops.FORCE_ENV, "pallas")
-    w_pal, sel_pal = greedytl(x, y, m, src, sm, num_classes=C)
-    assert np.array_equal(np.asarray(sel_jnp), np.asarray(sel_pal))
-    np.testing.assert_allclose(np.asarray(w_jnp), np.asarray(w_pal),
-                               rtol=1e-5, atol=1e-5)

@@ -1,12 +1,14 @@
-"""Pallas kernel sweeps: shapes x dtypes vs the pure-jnp oracles in ref.py
+"""Pallas kernel sweeps: shapes x dtypes vs the pure-jnp oracles in ref.py,
+and the GreedyTL trial kernel vs the jnp scorer the program runs
 (interpret mode on CPU — kernel bodies execute in Python)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.greedytl import loo_trials as loo_trials_jnp
 from repro.kernels.flash_attention import flash_attention
-from repro.kernels.loo_trials import loo_trials, loo_trials_ref
+from repro.kernels.loo_trials import loo_trials
 from repro.kernels.ref import (loo_trials_inv_reference, mha_reference,
                                rglru_reference, ssd_reference)
 from repro.kernels.rglru_scan import rglru_scan
@@ -141,10 +143,11 @@ def _bordering_inputs(R, M, C, seed, lam_scale=1.0):
     (200, 16, 4, 128),      # ragged rows (R % 8 != 0)
 ])
 def test_loo_trials_kernel_vs_ref(R, M, C, block_r):
-    """Pallas interpret path == pure-jnp oracle on random systems."""
+    """Pallas interpret path == the program's jnp scorer on random
+    systems."""
     shared, _, _ = _bordering_inputs(R, M, C, seed=R + M)
     out = loo_trials(*shared, block_r=block_r, interpret=True)
-    ref = loo_trials_ref(*shared)
+    ref = loo_trials_jnp(*shared)
     err = float(jnp.max(jnp.abs(out - ref))) / (float(jnp.max(ref)) + 1e-9)
     assert err < 2e-6, f"rel err={err}"
 
@@ -154,7 +157,7 @@ def test_loo_trials_kernel_vs_ref(R, M, C, block_r):
 def test_loo_trials_small_R_and_odd_tiles(R, block_r):
     """Regression: R < 8, R not a multiple of 8, and tuned/odd block_r
     values must all snap the row tile to a sublane multiple and pad the
-    tail with rmask=0 rows — not crash or mis-reduce. (The autotuner can
+    tail with rmask=0 rows — not crash or mis-reduce. (A caller can
     hand the kernel any block_r, and tiny fleets produce tiny R.)
 
     With R < D = 23 rows and the default penalties the ridge nearly
@@ -167,7 +170,7 @@ def test_loo_trials_small_R_and_odd_tiles(R, block_r):
     shared, _, _ = _bordering_inputs(R, 16, 7, seed=R * 31 + block_r,
                                      lam_scale=30.0)
     out = loo_trials(*shared, block_r=block_r, interpret=True)
-    ref = loo_trials_ref(*shared)
+    ref = loo_trials_jnp(*shared)
     err = float(jnp.max(jnp.abs(out - ref))) / (float(jnp.max(ref)) + 1e-9)
     assert err < 2e-6, f"rel err={err}"
 
@@ -188,7 +191,7 @@ def test_loo_trials_matches_inverse_formulation(seed):
         jnp.asarray(AtA), jnp.asarray(Aty), jnp.asarray(A_rm),
         jnp.asarray(y), jnp.asarray(rmask), jnp.asarray(cmask),
         jnp.asarray(lam_d), 16))
-    fac = np.asarray(loo_trials_ref(*shared))
+    fac = np.asarray(loo_trials_jnp(*shared))
     rel = np.abs(fac - ref)[valid] / np.maximum(np.abs(ref[valid]), 1e-6)
     assert rel.max() < 1e-5, rel.max()
 

@@ -12,27 +12,28 @@ only one process at a time may load the TPU library, and test workers
 import every test file.
 """
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import pytest
 
-REPO = os.path.join(os.path.dirname(__file__), "..")
-AUTOTUNE = os.path.join(REPO, "results", "benchmarks", "kernel_autotune.json")
 V5E_USABLE_HBM = 15.75e9          # of 16 GB, as XLA reports for one v5e
 # the city-250k round with the DCs on the lane axis plans 1.62e9 bytes
 # (1615564288); with the per-DC (55, 7) tiles of a vmapped trainer it
 # planned 9.53e9. The bound leaves that plan a quarter of room.
 CITY_CELL_ROUND_PLAN = 2.0e9
 BLOCK_RS = (64, 128, 256, 512)
+# GreedyTL's trial sweep as (R, D, M): R stacked rows n*C rounded up to a
+# power of two, D = C + min(k_max, M) carried columns, M candidate sources:
+# the paper's fleets (R512, R2048), the city's 14-observation DCs (R128),
+# and shallower (k_max 4, 8) or wider (M 32) source pools.
+LOO_TRIAL_SHAPES = ("R128_D23_M16", "R2048_D11_M16", "R2048_D15_M16",
+                    "R2048_D15_M8", "R2048_D23_M16", "R2048_D23_M32",
+                    "R512_D23_M16")
 
 
-def _autotune_keys():
-    with open(AUTOTUNE) as f:
-        backends = json.load(f)["backends"]
-    return sorted({key for entries in backends.values() for key in entries})
+def _trial_shape(key):
+    return tuple(int(part[1:]) for part in key.split("_"))
 
 
 @pytest.fixture(scope="module")
@@ -65,19 +66,38 @@ def _shape(sharding, shape, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("block_r", BLOCK_RS)
-@pytest.mark.parametrize("key", _autotune_keys())
+@pytest.mark.parametrize("key", LOO_TRIAL_SHAPES)
 def test_loo_trials_compiles_for_v5e(one_chip, key, block_r):
-    """The GreedyTL trial scorer compiles through Mosaic at every bucketed
-    shape the autotuner has recorded, at every tile it may choose."""
+    """The Pallas trial scorer compiles through Mosaic at every recorded
+    trial shape, at every row tile."""
     from repro.kernels import loo_trials as kernel
 
-    R, D, M = (int(part[1:]) for part in key.split("_"))
+    R, D, M = _trial_shape(key)
     s = functools.partial(_shape, one_chip)
     compiled = kernel.loo_trials.lower(
         s((R, D)), s((D, M)), s((R, M)), s((R,)), s((R,)), s((R,)),
         s((R,)), s((M,)), s((M,)), block_r=block_r,
         interpret=False).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("key", LOO_TRIAL_SHAPES)
+def test_greedytl_compiles_for_v5e(one_chip, key):
+    """GreedyTL compiles for the chip at every recorded trial shape, with
+    its trial scorer in plain XLA: the program holds no Mosaic kernel."""
+    from repro.core.greedytl import greedytl
+    from repro.data.synthetic_covtype import NUM_CLASSES as C
+    from repro.data.synthetic_covtype import NUM_FEATURES as F
+
+    R, D, M = _trial_shape(key)
+    n, k_max = R // C, D - C
+    assert 1 << (n * C - 1).bit_length() == R and C + min(k_max, M) == D
+    s = functools.partial(_shape, one_chip)
+    lowered = greedytl.__wrapped__.lower(
+        s((n, F)), s((n,), jnp.int32), s((n,)), s((M, F + 1, C)), s((M,)),
+        num_classes=C, k_max=k_max)
+    assert "tpu_custom_call" not in lowered.as_text()
+    lowered.compile()
 
 
 def test_city_round_fits_one_v5e(one_chip):
@@ -99,8 +119,7 @@ def test_city_cell_round_fits_one_v5e(one_chip):
 def _city_round_fits(one_chip, K: int, iters: int):
     """Compile the round as a TPU lowers it: the program asks
     ``jax.default_backend()`` for its TPU path, which a described chip
-    does not answer, so the test answers it; GreedyTL's trial scorer is
-    held to its jnp form, so that no autotuner runs here."""
+    does not answer, so the test answers it."""
     from repro.core.cityscan import _city_round_program, city_fleet_pad
     from repro.data.synthetic_covtype import NUM_CLASSES, make_covtype_like
 
@@ -114,7 +133,6 @@ def _city_round_fits(one_chip, K: int, iters: int):
             s((n_test, NUM_CLASSES)))
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(jax, "default_backend", lambda: "tpu")
-        mp.setenv("REPRO_KERNEL_FORCE", "jnp")
         compiled = _city_round_program.__wrapped__(NUM_CLASSES, iters).lower(
             *args).compile()
     mem = compiled.memory_analysis()
